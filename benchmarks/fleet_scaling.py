@@ -910,6 +910,9 @@ def main(argv=None) -> int:
                     help="skip the PR 1 serial-baseline scaling rows")
     args = ap.parse_args(argv)
 
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     rows: list[Row] = []
     if args.quick:
         rows += backend_matrix(

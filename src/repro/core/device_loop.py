@@ -101,7 +101,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core.discretize import DeviceLeverTable, shield_update
+from repro.core.discretize import (MAX_SPLIT_BINS, DeviceLeverTable,
+                                   shield_update)
 from repro.core.heatmap import node_grid_shape
 from repro.core.policy import _sample_actions
 from repro.data.workloads import pack_device_workloads, device_workload_reason
@@ -129,7 +130,7 @@ TICK_BUDGET = 192
 #: the episode program) every batch — tables are padded up this ladder
 #: instead, so adaptation only recompiles on a ladder crossing. Indices are
 #: clipped to ``n_valid`` so padded slots are unreachable.
-_BIN_BUCKETS = (16, 32, 64, 128, 256, 512)
+_BIN_BUCKETS = (16, 32, 64, 128, 256, MAX_SPLIT_BINS)
 
 
 def build_packed_tables(table: DeviceLeverTable,
@@ -214,7 +215,8 @@ class DeviceEpisodeRunner:
     def _resolve_mesh(self):
         """The cluster-sharding mesh (DESIGN.md §11): an explicit ``Mesh``
         from the configurator, or (``"auto"``) ``fleet_mesh()`` whenever the
-        fleet size divides the visible device count."""
+        fleet size divides the visible device count. Resolved once per
+        runner; an ``"auto"`` fallback to one device says why."""
         opt = getattr(self.cfgr, "mesh_opt", "auto")
         if opt in (None, "off"):
             return None
@@ -222,10 +224,12 @@ class DeviceEpisodeRunner:
 
         mesh = fleet_mesh() if opt == "auto" else opt
         if mesh is not None and self.env.n_clusters % mesh.size != 0:
+            reason = (f"fleet N={self.env.n_clusters} does not divide the "
+                      f"{mesh.size}-device mesh")
             if opt != "auto":
-                raise ValueError(
-                    f"fleet N={self.env.n_clusters} does not divide the "
-                    f"{mesh.size}-device mesh")
+                raise ValueError(reason)
+            print(f"[device-loop] cluster sharding (§11): off — {reason} "
+                  "(single-device program)")
             mesh = None
         return mesh
 
@@ -480,13 +484,11 @@ class DeviceEpisodeRunner:
         """Wrap an episode closure in the fleet ``shard_map`` — specs come
         from ``fleet_episode_specs``, the ONE definition shared with the
         epoch mega-scan (whose shard_map sits inside its scan body)."""
-        from jax.experimental.shard_map import shard_map
-
         from repro.distribution.sharding import fleet_episode_specs
 
         in_specs, out_specs = fleet_episode_specs(self.mesh, r_max, shield)
-        return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def _program(self, skey: tuple, consts: dict):
         if skey in self._programs:

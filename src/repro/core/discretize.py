@@ -80,6 +80,14 @@ def _trailing_run(mask: np.ndarray) -> int:
     return int(mask.size if nz.size == 0 else mask.size - 1 - nz[-1])
 
 
+#: most bins a §2.4.1 split may leave on one lever — the top of the device
+#: bin-table ladder (``core.device_loop._BIN_BUCKETS``). A fleet-shared
+#: discretiser sees every cluster's assignments, so same-bin streaks recur
+#: every few records; unbounded halving grew the bin count exponentially
+#: with fleet size (out of host memory at N=1024 on the tune launcher).
+MAX_SPLIT_BINS = 512
+
+
 class DynamicBins:
     """Adaptive binning state for one continuous lever."""
 
@@ -173,7 +181,8 @@ class DynamicBins:
             self._extend(top=False)
             self._bot_streak = 0
         if self._same_streak >= self.split_after:
-            self._split()
+            if 2 * self.n_bins <= MAX_SPLIT_BINS:
+                self._split()
             self._same_streak = 0
         self._maybe_merge()
 

@@ -16,9 +16,9 @@ tiers, selected by ``mode``:
 * ``"interpret"`` — the same ``pallas_call`` in interpret mode (jnp ops per
   grid cell; the debugging tier — slow, but executes the literal kernel);
 * ``"xla"`` — an XLA lowering of the *same tick math* (shared helpers, a
-  ``lax.scan`` over ticks): the compiled fast path off-TPU, where this jax
-  version has no Pallas CPU/GPU lowering at all (``pallas_call`` with
-  ``interpret=False`` raises on the CPU backend).
+  ``lax.scan`` over ticks): the compiled path off-TPU, where a TPU
+  ``pallas_call`` has no lowering (``interpret=False`` raises on the CPU
+  backend).
 
 ``pallas_mode()`` picks the tier for the current backend;
 ``DISPATCH_COUNTS`` records which tiers actually traced, and setting
@@ -79,6 +79,11 @@ _C_TB, _C_MAXB, _C_ACOMP, _C_CCOLL, _C_BMEM, _C_KVP, _C_OVH, _C_SLOWCAP, \
     _C_BACKUP, _C_FAIL, _C_INFLIGHT = range(11)
 CONSTS_ROWS = 16  # padded to a sublane multiple
 
+#: VMEM for one grid step's double-buffered blocks on the Pallas tiers:
+#: half of v5e's 16 MiB default scoped limit, leaving the rest to the head
+#: carry and the sort network's temporaries (``tick_tile``)
+_TILE_VMEM_BYTES = 8 << 20
+
 #: mode -> number of times a window program traced through that tier; the
 #: compiled-pallas CI smoke asserts the interpret tier stays at zero
 DISPATCH_COUNTS: dict = {"mosaic": 0, "interpret": 0, "xla": 0}
@@ -104,6 +109,21 @@ def head_budget(S: int, p99_k: int) -> int:
     while P < S + p99_k:
         P *= 2
     return P - S
+
+
+def tick_tile(T: int, S: int, block_n: int) -> int:
+    """Ticks per grid step of the Pallas tiers: the largest divisor of T
+    that is a multiple of 8 (the sublane tile of the (ticks, clusters)
+    blocks) and whose double-buffered blocks fit ``_TILE_VMEM_BYTES`` — the
+    whole window when it fits, or when T has no such divisor. Per tick a
+    block holds 9 (T, N) operand rows, two S-lane tiles and 12 output rows,
+    each ``block_n`` f32 wide."""
+    budget = _TILE_VMEM_BYTES
+    per_tick = 2 * 4 * block_n * (9 + 2 * S + 12)
+    if T * per_tick <= budget or T % 8:
+        return T
+    fits = [d for d in range(8, T, 8) if T % d == 0 and d * per_tick <= budget]
+    return max(fits, default=T)
 
 
 def pack_tick_consts(cc: dict, mc: dict, spec, chips: int, xp=jnp):
@@ -187,52 +207,60 @@ def _tick_step(backlog, sfree, rate, size, z, u_s, u_r, u_f, active, fm, cv,
     return carry, ys
 
 
-def _sort_axis0(x):
-    """Ascending bitonic sort along axis 0 (power-of-two length), written
-    as reshape compare-exchange stages — pure min/max/reshape with no
-    captured index constants, so the SAME code traces inside the Pallas
-    kernel body (which forbids constant operands) and in the XLA tier,
-    and never touches XLA's general sort (~50x slower on CPU)."""
+def _partner(x, j):
+    """``x[i ^ j]`` along axis 0 (power-of-two length): the compare-exchange
+    partner of every slot, as two sublane rotations and an iota-mask select.
+    The mask compares the rotated iota with ``i ^ j``, so the pick does not
+    depend on the rotation's direction convention. Rotations and
+    ``broadcasted_iota`` lower on every tier (Mosaic has no lowering for the
+    strided slices or sub-8 sublane reshapes a slice-based network needs)."""
     L = x.shape[0]
-    W = x.shape[1:]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    src = pltpu.roll(lane, j, 0)
+    return jnp.where(src == (lane ^ j), pltpu.roll(x, j, 0),
+                     pltpu.roll(x, L - j, 0))
+
+
+def _sort_axis0(x, descending=False):
+    """Bitonic sort along axis 0 (power-of-two length) — pure min/max,
+    rotations and iota masks with no captured index constants, so the SAME
+    code traces inside the Pallas kernel body (which forbids constant
+    operands) and in the XLA tier, and never touches XLA's general sort
+    (~50x slower on CPU). Any sorting network returns the same values, so
+    tiers agree to the bit."""
+    L = x.shape[0]
     assert L & (L - 1) == 0, f"lane count {L} must be a power of two"
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
     k = 2
     while k <= L:
         j = k // 2
         while j >= 1:
-            # pairs (i, i^j) = adjacent slots after grouping axis 0 into
-            # (k-blocks, pair groups, 2, j); block parity = sort direction
-            v = x.reshape((L // k, k // (2 * j), 2, j) + W)
-            a, b = v[:, :, 0], v[:, :, 1]
-            mn, mx = jnp.minimum(a, b), jnp.maximum(a, b)
-            asc = jnp.stack([mn, mx], axis=2).reshape((L // k, k) + W)
-            if L // k == 1:
-                x = asc.reshape((L,) + W)
-            else:
-                desc = jnp.stack([mx, mn], axis=2).reshape((L // k, k) + W)
-                x = jnp.stack([asc[0::2], desc[1::2]], axis=1) \
-                    .reshape((L,) + W)
+            xp = _partner(x, j)
+            # slot i keeps the min when its k-block ascends and it is the
+            # lower slot of its pair (or descends and it is the upper one)
+            asc = ((lane & k) == 0) != descending
+            take_min = asc == ((lane & j) == 0)
+            x = jnp.where(take_min, jnp.minimum(x, xp), jnp.maximum(x, xp))
             j //= 2
         k *= 2
     return x
 
 
 def _merge_head(head, srt):
-    """Merge a tick's ascending sorted lanes (S, W) into the ascending
-    streaming top-K head (K, W). ``concat(head, reversed(srt))`` ascends
-    then descends — a bitonic sequence — so one O(log(K+S)) bitonic merge
-    (not a full sort) re-sorts it; the largest K survive."""
+    """Merge a tick's *descending* sorted lanes (S, W) into the ascending
+    streaming top-K head (K, W). ``concat(head, srt)`` ascends then
+    descends — a bitonic sequence — so one O(log(K+S)) bitonic merge (not a
+    full sort) re-sorts it ascending; the largest K survive."""
     S = srt.shape[0]
-    x = jnp.concatenate([head, srt[::-1]], axis=0)
+    x = jnp.concatenate([head, srt], axis=0)
     P = x.shape[0]
-    W = x.shape[1:]
     assert P & (P - 1) == 0, f"head+lanes {P} must be a power of two"
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
     j = P // 2
     while j >= 1:
-        v = x.reshape((P // (2 * j), 2, j) + W)
-        a, b = v[:, 0], v[:, 1]
-        x = jnp.stack([jnp.minimum(a, b), jnp.maximum(a, b)],
-                      axis=1).reshape((P,) + W)
+        xp = _partner(x, j)
+        x = jnp.where((lane & j) == 0, jnp.minimum(x, xp),
+                      jnp.maximum(x, xp))
         j //= 2
     return x[S:]
 
@@ -241,14 +269,22 @@ def _sum0(x):
     """Pairwise tree sum along axis 0 (power-of-two length). XLA's reduce
     picks its accumulation order from the operand layout, so the same
     axis-0 ``sum`` rounds differently on (S, N) vs (S, T, N) operands;
-    spelling the tree out keeps the lane sum bitwise-identical across
-    tiers whatever the trailing shape."""
+    spelling the tree out (slot i adds its partner i ^ s, stride s = 1, 2,
+    4, ...; slot 0 ends with the total) keeps the lane sum bitwise-identical
+    across tiers whatever the trailing shape."""
     L = x.shape[0]
     assert L & (L - 1) == 0, f"lane count {L} must be a power of two"
-    while x.shape[0] > 1:
-        v = x.reshape((x.shape[0] // 2, 2) + x.shape[1:])
-        x = v[:, 0] + v[:, 1]
-    return x[0]
+    s = 1
+    while s < L:
+        x = x + _partner(x, s)
+        s *= 2
+    return _row(x, 0)
+
+
+def _row(x, i):
+    """``x[i]`` for a static non-negative i, as a static slice (a negative
+    or traced index lowers to ``dynamic_slice``, which Mosaic refuses)."""
+    return jax.lax.index_in_dim(x, i, axis=0, keepdims=False)
 
 
 def _gather0(x, idx):
@@ -266,31 +302,31 @@ def _lane_stats(uw, z2, T_b, qd, service, batch, wm, S):
 
     Returns (stats5, srt): ``stats5`` = (lane_sum, p50, p95, p99, max)
     over the valid lanes (lane < n_s, window ticks only — rows at
-    non-window ticks are unused by every caller), and the ascending sorted
+    non-window ticks are unused by every caller), and the descending sorted
     lanes for the caller's streaming top-K head merge. Every op is
     elementwise or an axis-0 reduction, so W may be a single tick's (N,)
     block (the Pallas tiers) or the whole window's (T, N) at once (the XLA
     tier) with bitwise-identical per-column results. Quantiles interpolate
     exactly like the caller-side ``_lerp_quantile``; with invalid lanes
-    sorted to the front as -inf, the ascending rank r of a valid lane lives
-    at index S - n_s + r."""
+    sorted to the back as -inf, the ascending rank r of a valid lane lives
+    at index n_s - 1 - r."""
     lat = uw * T_b[None] + qd[None] + service[None] * (1.0 + 0.1 * z2)
     n_s = jnp.clip(batch.astype(jnp.int32), 1, S)
     lane = jax.lax.broadcasted_iota(jnp.int32, (S,) + batch.shape, 0)
     valid = (lane < n_s[None]) & (wm > 0.0)[None]
     lane_sum = _sum0(jnp.where(valid, lat, 0.0))
-    srt = _sort_axis0(jnp.where(valid, lat, -jnp.inf))
-    base = (S - n_s).astype(jnp.int32)
+    srt = _sort_axis0(jnp.where(valid, lat, -jnp.inf), descending=True)
+    top = n_s - 1
 
     def q_at(q):
         pos = (n_s - 1).astype(jnp.float32) * (q / 100.0)
         lo = jnp.floor(pos).astype(jnp.int32)
         hi = jnp.ceil(pos).astype(jnp.int32)
-        a = _gather0(srt, base + lo)
-        b = _gather0(srt, base + hi)
+        a = _gather0(srt, top - lo)
+        b = _gather0(srt, top - hi)
         return a + (pos - lo.astype(jnp.float32)) * (b - a)
 
-    stats5 = (lane_sum, q_at(50.0), q_at(95.0), q_at(99.0), srt[-1])
+    stats5 = (lane_sum, q_at(50.0), q_at(95.0), q_at(99.0), _row(srt, 0))
     return stats5, srt
 
 
@@ -304,8 +340,11 @@ def _tick_window_kernel(state_ref, c_ref, rate_ref, size_ref, z_ref, us_ref,
                         *, T: int, S: int, K: int, noise: float,
                         retention_s: float, straggler_prob: float,
                         slo: float, shi: float):
-    """One exploration window for a (BLOCK_N,) cluster block: the T-tick
-    queueing recurrence in registers, lanes reduced per tick (Pallas tiers)."""
+    """One T-tick tile of an exploration window for a (BLOCK_N,) cluster
+    block: the queueing recurrence in registers, lanes reduced per tick
+    (Pallas tiers). Grid axis 1 walks the window's tiles in order; the state
+    and head output blocks stay resident across it and carry the recurrence
+    from one tile to the next."""
     cv = tuple(c_ref[i] for i in range(11))
     T_b = cv[0]
 
@@ -325,9 +364,13 @@ def _tick_window_kernel(state_ref, c_ref, rate_ref, size_ref, z_ref, us_ref,
             stats_ref[r, t] = stats5[r]
         return backlog, sfree, head
 
-    head0 = jnp.full((K,) + state_ref.shape[1:], -jnp.inf, jnp.float32)
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        state_out_ref[...] = state_ref[...]
+        head_ref[...] = jnp.full(head_ref.shape, -jnp.inf, jnp.float32)
+
     backlog, sfree, head = jax.lax.fori_loop(
-        0, T, tick, (state_ref[0], state_ref[1], head0))
+        0, T, tick, (state_out_ref[0], state_out_ref[1], head_ref[...]))
     state_out_ref[0] = backlog
     state_out_ref[1] = sfree
     head_ref[...] = head
@@ -425,28 +468,33 @@ def fleet_tick_window(state, consts, rate, size, z, u_strag, u_raw, u_fail,
         return _window_xla(state, consts, rate, size, z, u_strag, u_raw,
                            u_fail, active, wmask, u_wait, z2a, fmult, **kw)
     bn = min(block_n, N)
-    grid = (pl.cdiv(N, bn),)
+    tT = tick_tile(T, S, bn)
+    grid = (pl.cdiv(N, bn), T // tT)
     vm = pltpu.VMEM
-    tn = lambda i: (0, i)          # (rows, cluster-block) tiles
-    lane = lambda i: (0, 0, i)     # (ticks, lanes, cluster-block)
-    kernel = functools.partial(_tick_window_kernel, **kw)
+    blk = lambda i, t: (0, i)           # per-cluster-block, resident over t
+    tn = lambda i, t: (t, i)            # (ticks, cluster-block)
+    lane = lambda i, t: (t, 0, i)       # (ticks, lanes, cluster-block)
+    row = lambda i, t: (0, t, i)        # (rows, ticks, cluster-block)
+    kernel = functools.partial(_tick_window_kernel, **dict(kw, T=tT))
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((2, bn), tn, memory_space=vm),
-            pl.BlockSpec((CONSTS_ROWS, bn), tn, memory_space=vm),
-        ] + [pl.BlockSpec((T, bn), tn, memory_space=vm)] * 8 + [
-            pl.BlockSpec((T, S, bn), lane, memory_space=vm),
-            pl.BlockSpec((T, S, bn), lane, memory_space=vm),
-            pl.BlockSpec((T, bn), tn, memory_space=vm),
+            pl.BlockSpec((2, bn), blk, memory_space=vm),
+            pl.BlockSpec((CONSTS_ROWS, bn), blk, memory_space=vm),
+        ] + [pl.BlockSpec((tT, bn), tn, memory_space=vm)] * 8 + [
+            pl.BlockSpec((tT, S, bn), lane, memory_space=vm),
+            pl.BlockSpec((tT, S, bn), lane, memory_space=vm),
+            pl.BlockSpec((tT, bn), tn, memory_space=vm),
         ],
         out_specs=[
-            pl.BlockSpec((2, bn), tn, memory_space=vm),
-            pl.BlockSpec((7, T, bn), lane, memory_space=vm),
-            pl.BlockSpec((5, T, bn), lane, memory_space=vm),
-            pl.BlockSpec((K, bn), tn, memory_space=vm),
+            pl.BlockSpec((2, bn), blk, memory_space=vm),
+            pl.BlockSpec((7, tT, bn), row, memory_space=vm),
+            pl.BlockSpec((5, tT, bn), row, memory_space=vm),
+            pl.BlockSpec((K, bn), blk, memory_space=vm),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         out_shape=[
             jax.ShapeDtypeStruct((2, N), jnp.float32),
             jax.ShapeDtypeStruct((7, T, N), jnp.float32),

@@ -134,8 +134,6 @@ def _cost_triple(compiled) -> tuple[float, float, dict]:
     the full scanned compile is kept for memory analysis + the pass gate.
     """
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     coll = collective_bytes_from_hlo(compiled.as_text())
     return (float(cost.get("flops", 0.0)),
             float(cost.get("bytes accessed", 0.0)), coll)

@@ -91,6 +91,9 @@ def main(argv=None):
                          "continue mid-tuning")
     args = ap.parse_args(argv)
 
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     from repro.monitoring import flush_guard
     from repro.serve import ServeController
 

@@ -19,6 +19,8 @@ import numpy as np
 
 
 def main(argv=None):
+    """Run the pipeline; returns the tuned ``Configurator`` (None when
+    interrupted before the first record)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--env", choices=["sim", "local"], default="sim")
     ap.add_argument("--arch", default="smollm_135m")
@@ -73,6 +75,9 @@ def main(argv=None):
     ap.add_argument("--out", default="experiments/tune")
     args = ap.parse_args(argv)
 
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     from repro.core import AutoTuner
     from repro.data.workloads import fleet_workloads, get_workload
     from repro.engine import FleetEnv, LocalEngine, SimCluster
@@ -191,7 +196,7 @@ def main(argv=None):
         interrupted = True
         print(f"[interrupted] final metrics dump at {out}/metrics.prom")
     if interrupted and not cfgr.history:
-        return
+        return None
     best = min(cfgr.history, key=lambda r: r.p99_ms)
     print(f"[done] best p99 {best.p99_ms:.0f} ms "
           f"({100 * (1 - best.p99_ms / base_p99):.0f}% below default)")
@@ -206,6 +211,7 @@ def main(argv=None):
         {"default_p99_ms": base_p99, "best_p99_ms": best.p99_ms,
          "best_config": best.config, "history": hist}, indent=2))
     print(f"[done] wrote {out}/analysis.json and {out}/history.json")
+    return cfgr
 
 
 if __name__ == "__main__":

@@ -2,7 +2,9 @@
 from __future__ import annotations
 
 import math
+import os
 import time
+from pathlib import Path
 from typing import Any, Callable, Iterator
 
 import jax
@@ -10,6 +12,23 @@ import jax.numpy as jnp
 import numpy as np
 
 PyTree = Any
+
+#: the persistent compile cache's fixed home: ``.jax_cache/`` at the
+#: checkout root (a moving path never hits — it is part of the cache key)
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point
+    (launchers, benchmarks, ``chip_smoke.py``; never at import). Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and nothing
+    is set here; otherwise the cache lives at ``COMPILE_CACHE_DIR``.
+    Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 def tree_size(tree: PyTree) -> int:

@@ -488,6 +488,25 @@ def test_epoch_summary_and_off_modes_skip_records():
         cfgr.run_epoch(1, records="nope")
 
 
+def test_mesh_fallback_is_reported_not_silent(monkeypatch, capsys):
+    """mesh="auto" on a device count that N does not divide runs on one
+    device and prints why; an explicit mesh N does not divide raises."""
+    from repro.core.device_loop import DeviceEpisodeRunner
+    from repro.distribution import sharding
+
+    class ThreeDevices:
+        size = 3
+
+    monkeypatch.setattr(sharding, "fleet_mesh", lambda: ThreeDevices())
+    env = _fleet("jax", 4)
+    runner = DeviceEpisodeRunner(_cfgr(env, device_loop="on", mesh="auto"))
+    assert runner.mesh is None
+    assert "N=4 does not divide the 3-device mesh" in capsys.readouterr().out
+    with pytest.raises(ValueError, match="does not divide"):
+        DeviceEpisodeRunner(_cfgr(env, device_loop="on",
+                                  mesh=ThreeDevices()))
+
+
 def test_epoch_summary_bin_replay_matches_full_mode():
     """The device-side (lever, bin) count tensor replayed at the epoch
     boundary must land the same §2.4.1 hit totals as full-mode

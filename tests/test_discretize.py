@@ -26,6 +26,16 @@ def test_same_bin_streak_halves_bin_size():
     assert b.n_bins == 20  # paper: '20 bins after this initial halving'
 
 
+def test_splits_stop_at_the_bin_cap():
+    """A fleet-sized replay of one bin (thousands of same-bin assignments)
+    halves the bins only up to MAX_SPLIT_BINS, not once per streak."""
+    from repro.core.discretize import MAX_SPLIT_BINS
+
+    b = _bins(split_after=5, merge_after=10**9)
+    b.record_many(np.full(70, 4))
+    assert MAX_SPLIT_BINS // 2 < b.n_bins <= MAX_SPLIT_BINS
+
+
 def test_top_bin_streak_extends_range():
     b = _bins(extend_after=3)
     hi0 = b._edges[-1]

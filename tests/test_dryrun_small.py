@@ -24,7 +24,8 @@ from repro.configs.base import InputShape
 from repro.distribution.steps import make_step_for_cell
 from repro.launch.dryrun import collective_bytes_from_hlo, roofline_terms
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 out = {}
 cells = [
     ("smollm_135m", InputShape("t", 128, 8, "train")),
@@ -39,8 +40,6 @@ for arch, shape in cells:
         compiled = bundle.lower().compile()
         hlo = compiled.as_text()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-            cost = cost[0] if cost else {}
         coll = collective_bytes_from_hlo(hlo)
         terms = roofline_terms(float(cost.get("flops", 0.0)),
                                float(cost.get("bytes accessed", 0.0)), coll, 8)

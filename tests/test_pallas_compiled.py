@@ -129,6 +129,57 @@ def test_xla_tier_bitwise_equals_interpret_tier():
     assert DISPATCH_COUNTS["xla"] == before["xla"] + 1
 
 
+def test_tiled_interpret_tier_bitwise_equals_xla_tier(monkeypatch):
+    """A window longer than one VMEM tile runs as several tick tiles on the
+    Pallas tiers, the state and head carried across them in the resident
+    output blocks — still bitwise-equal to the untiled xla tier. A small
+    VMEM budget forces the tiling on a fresh shape."""
+    from repro.kernels import fleet_tick
+
+    T, N, S = 48, 16, 8
+    monkeypatch.setattr(fleet_tick, "_TILE_VMEM_BYTES",
+                        16 * 2 * 4 * 8 * (21 + 2 * S))
+    assert fleet_tick.tick_tile(T, S, 8) == 16
+    ops, kw = _kernel_inputs(T=T, N=N, S=S)
+    a = fleet_tick_window(*ops.values(), **kw, p99_k=4, block_n=8,
+                          mode="interpret")
+    b = fleet_tick_window(*ops.values(), **kw, p99_k=4, block_n=8,
+                          mode="xla")
+    for name, x, y in zip(("state", "ys", "stats", "head"), a, b):
+        assert np.array_equal(np.asarray(x), np.asarray(y),
+                              equal_nan=True), name
+
+
+@pytest.mark.parametrize("L", [8, 64])
+def test_lane_networks_match_numpy(L):
+    """The rotation-and-mask networks the tiers share: bitonic sort in both
+    directions, the bitonic head merge (top K of head ∪ tick lanes) and the
+    pairwise tree sum, against numpy on lanes with -inf padding."""
+    import jax.numpy as jnp
+
+    from repro.kernels.fleet_tick import _merge_head, _sort_axis0, _sum0
+
+    # the rotations have no eager rule: the networks only ever run traced
+    _sort_axis0 = jax.jit(_sort_axis0, static_argnames="descending")
+    _merge_head, _sum0 = jax.jit(_merge_head), jax.jit(_sum0)
+    rng = np.random.default_rng(L)
+    x = rng.standard_normal((L, 3, 5)).astype(np.float32)
+    x[rng.random(x.shape) < 0.2] = -np.inf
+    asc = np.sort(x, axis=0)
+    assert np.array_equal(np.asarray(_sort_axis0(jnp.asarray(x))), asc)
+    assert np.array_equal(
+        np.asarray(_sort_axis0(jnp.asarray(x), descending=True)), asc[::-1])
+    head = np.sort(rng.standard_normal((L, 3, 5)).astype(np.float32), axis=0)
+    merged = _merge_head(jnp.asarray(head), jnp.asarray(asc[::-1]))
+    top = np.sort(np.concatenate([head, x]), axis=0)[-L:]
+    assert np.array_equal(np.asarray(merged), top)
+    y = rng.random((L, 4)).astype(np.float32)
+    tree = y
+    while tree.shape[0] > 1:
+        tree = tree[0::2] + tree[1::2]
+    assert np.array_equal(np.asarray(_sum0(jnp.asarray(y))), tree[0])
+
+
 def test_require_compiled_turns_interpret_trace_into_error(monkeypatch):
     """The CI job's guard: with REPRO_REQUIRE_COMPILED set, an interpret
     trace raises instead of silently running the debug tier; the compiled

@@ -12,7 +12,8 @@ from repro.models import lm
 
 
 def _mesh(shape=(1, 1)):
-    return jax.make_mesh(shape, ("data", "model"))
+    return jax.make_mesh(shape, ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def test_meshspec_detects_axes():
