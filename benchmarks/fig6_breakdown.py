@@ -21,12 +21,25 @@ def run(seed: int = 3, updates: int = 4) -> list[Row]:
     env.reset()
     cfgr = tuner.build_configurator(steps_per_episode=5, episodes_per_update=2,
                                     window_s=240.0)
+    # configuration generation and the network update are host phases:
+    # timed as spans around the agent's calls, spread over the steps
+    from repro.monitoring import span, spans
+
+    agent = cfgr.agent
+    for attr, name in (("act", "fig6.generation"), ("update", "fig6.update")):
+        def timed(*a, _fn=getattr(agent, attr), _name=name, **kw):
+            with span(_name):
+                return _fn(*a, **kw)
+        setattr(agent, attr, timed)
+    spans.TOTALS.reset()
     cfgr.tune(updates)
-    phases = {k: [] for k in ("generation_s", "loading_s", "stabilisation_s",
-                              "update_s")}
-    for r in cfgr.history:
-        for k in phases:
-            phases[k].append(r.phases[k])
+    per_step = {k: spans.TOTALS.spans[f"fig6.{k}"].seconds
+                / len(cfgr.history) for k in ("generation", "update")}
+    phases = {"generation_s": [per_step["generation"]],
+              "loading_s": [r.phases["loading_s"] for r in cfgr.history],
+              "stabilisation_s": [r.phases["stabilisation_s"]
+                                  for r in cfgr.history],
+              "update_s": [per_step["update"]]}
     total = sum(np.mean(v) for v in phases.values())
     rows = []
     for k, v in phases.items():

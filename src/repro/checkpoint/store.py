@@ -22,7 +22,6 @@ from __future__ import annotations
 import json
 import shutil
 import threading
-import time
 from pathlib import Path
 from typing import Any, Optional
 
@@ -71,7 +70,6 @@ class CheckpointStore:
         self.dir.mkdir(parents=True, exist_ok=True)
         self.keep = keep
         self._thread: Optional[threading.Thread] = None
-        self.last_write_s = 0.0
 
     # ------------------------------------------------------------------ save
     def save(self, step: int, tree: PyTree, *, extra: Optional[dict] = None) -> Path:
@@ -94,7 +92,6 @@ class CheckpointStore:
             self._thread = None
 
     def _write(self, step: int, host_flat: dict[str, np.ndarray], extra: dict) -> Path:
-        t0 = time.perf_counter()
         for stale in self.dir.glob(".tmp-*"):
             shutil.rmtree(stale, ignore_errors=True)  # GC torn writes
         tmp = self.dir / f".tmp-{step}"
@@ -111,7 +108,6 @@ class CheckpointStore:
             shutil.rmtree(final)
         tmp.rename(final)  # commit point
         self._gc()
-        self.last_write_s = time.perf_counter() - t0
         return final
 
     def _gc(self) -> None:

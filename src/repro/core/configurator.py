@@ -8,22 +8,22 @@ drives the paper's episode loop against it:
   -> buffer events during loading -> wait for stabilisation -> measure
   latency -> reward -> (end of episode) REINFORCE update.
 
-The per-phase wall-clock (generation / loading / stabilisation / reward) is
-recorded for the Fig 6 execution-breakdown reproduction.
+Each step records its simulated loading and stabilisation seconds for the
+Fig 6 execution-breakdown reproduction; the host's own phases are named
+spans (``repro.monitoring.spans``).
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Optional, Protocol, Sequence
 
-import jax
 import numpy as np
 
 from repro.core.discretize import (DeviceLeverTable, LeverDiscretiser,
                                    LeverSpec, ShieldSpec, shield_update)
 from repro.core.heatmap import HeatmapEncoder, HeatmapSpec
 from repro.core.policy import ReinforceAgent, Trajectory
+from repro.monitoring.spans import span, step
 
 
 class MetricsWindow(Protocol):
@@ -96,7 +96,7 @@ class StepRecord:
     reward: float
     p99_ms: float
     clock_s: float
-    phases: dict  # generation/loading/stabilisation/update seconds
+    phases: dict  # simulated loading/stabilisation seconds
 
 
 @dataclass
@@ -271,10 +271,8 @@ class Configurator:
         window = self._last_window or self.env.observe(self.window_s)
         for _ in range(self.steps_per_episode):
             state = self._encode(window, config)
-            t0 = time.perf_counter()
             a = self.agent.act(state, explore=explore)
             lever, direction = self.agent.action_decode(a)
-            gen_s = time.perf_counter() - t0
 
             new_config = self.disc.apply(config, lever, direction)
             report = self.env.apply_config(new_config)
@@ -293,8 +291,8 @@ class Configurator:
             records.append(StepRecord(
                 lever=lever, direction=direction, config=dict(new_config),
                 reward=reward, p99_ms=window.p99_ms, clock_s=window.clock_s,
-                phases={"generation_s": gen_s, "loading_s": report["load_s"],
-                        "stabilisation_s": stab_s, "update_s": 0.0},
+                phases={"loading_s": report["load_s"],
+                        "stabilisation_s": stab_s},
             ))
             config = new_config
         self._last_window = window
@@ -349,19 +347,12 @@ class Configurator:
             states = self._encode_fleet(windows, configs)
             mask = (table.shield_mask(idx, lkg, radius, ranked)
                     if spec is not None else None)
-            t0 = time.perf_counter()
             if device:
-                # block before reading the clock: jax dispatch is async, so
-                # an unsynchronised stop would under-report generation time
-                # in the Fig-6 phase breakdown
-                acts = jax.block_until_ready(self.agent.act_batch_device(
+                actions = np.asarray(self.agent.act_batch_device(
                     states, explore=explore, mask=mask))
-                gen_s = (time.perf_counter() - t0) / N
-                actions = np.asarray(acts)
             else:
                 actions = self.agent.act_batch(states, explore=explore,
                                                mask=mask)
-                gen_s = (time.perf_counter() - t0) / N
             if spec is not None:
                 # the device twin's diversion signal: a step counts as
                 # clamped when the mask removed the action the policy's
@@ -443,10 +434,8 @@ class Configurator:
                     lever=lever, direction=direction,
                     config=dict(new_configs[i]), reward=reward,
                     p99_ms=windows[i].p99_ms, clock_s=windows[i].clock_s,
-                    phases={"generation_s": gen_s,
-                            "loading_s": reports[i]["load_s"],
-                            "stabilisation_s": float(stabs[i]),
-                            "update_s": 0.0},
+                    phases={"loading_s": reports[i]["load_s"],
+                            "stabilisation_s": float(stabs[i])},
                 ))
             configs = new_configs
         if spec is not None:
@@ -528,8 +517,13 @@ class Configurator:
         Against a FleetTuningEnv the N episodes run in parallel, one per
         cluster (as ≤2 fused device programs per pass when the §10 loop is
         available); serially otherwise."""
-        device = self.fleet and self.device_loop != "off" \
-            and self.device_loop_reason() is None
+        with step("tune.update", self.agent.n_updates):
+            return self._run_update()
+
+    def _run_update(self) -> dict:
+        with span("tune.check"):
+            device = self.fleet and self.device_loop != "off" \
+                and self.device_loop_reason() is None
         if self.device_loop == "on" and not device:
             raise RuntimeError(
                 f"device_loop='on' but: {self.device_loop_reason()}")
@@ -552,10 +546,8 @@ class Configurator:
                 t, r = self.run_episode()
                 trajs.append(t)
                 all_records.extend(r)
-        t0 = time.perf_counter()
         stats = self.agent.update(trajs)
-        upd_s = time.perf_counter() - t0
-        return self._finish_update(stats, all_records, upd_s)
+        return self._finish_update(stats, all_records)
 
     def _run_update_device(self) -> dict:
         """§10 outer iteration: one fused episode program per pass + ONE
@@ -569,13 +561,10 @@ class Configurator:
         work overlaps the device update."""
         runner = self._device_runner()
         passes = max(1, -(-self.episodes_per_update // self.env.n_clusters))
-        stats, all_records, upd_s = runner.run_cycle(passes=passes)
-        return self._finish_update(stats, all_records, upd_s)
+        stats, all_records = runner.run_cycle(passes=passes)
+        return self._finish_update(stats, all_records)
 
-    def _finish_update(self, stats: dict, all_records: list,
-                       upd_s: float) -> dict:
-        if all_records:
-            all_records[-1].phases["update_s"] = upd_s
+    def _finish_update(self, stats: dict, all_records: list) -> dict:
         self.history.extend(all_records)
         stats["p99_ms"] = all_records[-1].p99_ms if all_records else float("nan")
         return stats
@@ -618,12 +607,12 @@ class Configurator:
                 f"pipelined tuning needs the fused device loop: {reason}")
         runner = self._device_runner()
         passes = max(1, -(-self.episodes_per_update // self.env.n_clusters))
-        stats_list, records, upd_s = runner.run_pipelined(
+        stats_list, records = runner.run_pipelined(
             n_updates, passes=passes, depth=depth)
         per = len(records) // n_updates if records else 0
         for k, stats in enumerate(stats_list):
             recs = records[k * per:(k + 1) * per] if per else []
-            stats = self._finish_update(stats, recs, upd_s[k])
+            stats = self._finish_update(stats, recs)
             if callback:
                 callback(k, stats, self.history)
         return self.history
@@ -647,13 +636,10 @@ class Configurator:
         stats_list, recs = runner.run_epoch(k, passes=passes,
                                             records=records)
         if recs:
-            # same history bookkeeping as the sequential schedule; the
-            # update dispatch is fused into the epoch program, so there is
-            # no separable update_s to attribute (generation_s carries the
-            # whole epoch wall through the per-step amortisation)
+            # same history bookkeeping as the sequential schedule
             per = len(recs) // max(len(stats_list), 1)
             for i, stats in enumerate(stats_list):
-                self._finish_update(stats, recs[i * per:(i + 1) * per], 0.0)
+                self._finish_update(stats, recs[i * per:(i + 1) * per])
         return stats_list
 
     def tune_megascan(self, n_updates: int, *, k: int = 8,

@@ -108,6 +108,7 @@ from repro.core.policy import _sample_actions
 from repro.data.workloads import pack_device_workloads, device_workload_reason
 from repro.engine.simcluster import (_LEVER_TO_PACKED, _PACKERS,
                                      service_terms_arrays)
+from repro.monitoring.spans import count, span
 
 #: static-bundle -> times the episode program was traced; the §10 no-retrace
 #: test pins that re-running outer iterations never grows these.
@@ -123,6 +124,13 @@ EPOCH_DISPATCHES = [0]
 #: that walk it below (window+stab)/TICK_BUDGET see a truncated window —
 #: the documented §10 deviation.
 TICK_BUDGET = 192
+
+
+#: the episode program's outputs the host reads back after each batch (the
+#: states stay on the device for the update program)
+_RECORD_OUTS = ("lever", "bin", "rewards", "p99_ms", "clock_s", "load_s",
+                "stab_s", "actions", "breach_frac", "shield_clamped",
+                "shield_fallback", "budget_out")
 
 
 #: padded bin-table ladder: §2.4.1 splits double a lever's bin count between
@@ -612,20 +620,16 @@ class DeviceEpisodeRunner:
         tuner compiles (§10/§11): ``passes`` chained episode programs plus
         one update program, double-buffered so the host's record
         materialisation and bin replay overlap the in-flight update.
-        Returns ``(stats, records, upd_s)``. An always-on loop calling
+        Returns ``(stats, records)``. An always-on loop calling
         this per cycle never retraces (the no-retrace pin in
         tests/test_serve.py watches ``TRACE_COUNTS`` across cycles)."""
         b = self._dispatch_group(passes)
-        agent = self.cfgr.agent
-        t0 = time.perf_counter()
-        pending = agent.update_batch_async(b["states"], b["actions"],
-                                           b["rewards"])
-        dispatch_s = time.perf_counter() - t0
+        pending = self.cfgr.agent.update_batch_async(
+            b["states"], b["actions"], b["rewards"])
         records = self.finalize()   # host work, device update in flight
-        t1 = time.perf_counter()
-        stats = pending()
-        upd_s = dispatch_s + time.perf_counter() - t1
-        return stats, records, upd_s
+        with span("tune.wait"):
+            stats = pending()
+        return stats, records
 
     def _dispatch_group(self, passes: int) -> dict:
         """Dispatch one update's worth of chained episode batches and stack
@@ -656,23 +660,21 @@ class DeviceEpisodeRunner:
 
         ``depth=1`` IS the sequential schedule: it delegates to
         ``run_cycle`` per update and is pinned bitwise-equal to it
-        (tests/test_pallas_compiled.py). Returns ``(stats_list, records,
-        upd_s_list)``."""
+        (tests/test_pallas_compiled.py). Returns ``(stats_list,
+        records)``."""
         if updates <= 0:
-            return [], [], []
+            return [], []
         if depth <= 1:
-            out, recs, upds = [], [], []
+            out, recs = [], []
             for _ in range(updates):
-                stats, records, upd_s = self.run_cycle(passes=passes)
+                stats, records = self.run_cycle(passes=passes)
                 out.append(stats)
                 recs.extend(records)
-                upds.append(upd_s)
-            return out, recs, upds
+            return out, recs
         agent = self.cfgr.agent
         ahead = depth - 1
         groups: list = []
         thunks: list = []
-        upds: list = []
         nxt = 0
         for k in range(updates):
             # keep `ahead` episode groups dispatched past the current update
@@ -680,16 +682,13 @@ class DeviceEpisodeRunner:
                 groups.append(self._dispatch_group(passes))
                 nxt += 1
             b = groups[k]
-            t0 = time.perf_counter()
             thunks.append(agent.update_batch_async(
                 b["states"], b["actions"], b["rewards"]))
-            upds.append(time.perf_counter() - t0)
             groups[k] = None          # drop the host ref once enqueued
         records = self.finalize()     # blocks on the tail episode batch
-        t1 = time.perf_counter()
-        stats_list = [t() for t in thunks]
-        upds[-1] += time.perf_counter() - t1
-        return stats_list, records, upds
+        with span("tune.wait"):
+            stats_list = [t() for t in thunks]
+        return stats_list, records
 
     # ---------------------------------------------------------- epoch (§15)
     def run_epoch(self, k: int, *, passes: int = 1,
@@ -783,7 +782,6 @@ class DeviceEpisodeRunner:
         self.last_wall_s = time.perf_counter() - self._epoch_t0
         dev._draws = draws0
         agent.adopt_update(params, opt_state, k)
-        total_steps = k * passes * N * S
         self.chaos.add_wall(self.last_wall_s)
 
         # ---- adopt the final loop state (the finalize() contract) ----
@@ -803,10 +801,8 @@ class DeviceEpisodeRunner:
         self._config_idx = config_idx_f
         self._clock_mark = env.clock.copy()
 
-        gen_s = self.last_wall_s / max(total_steps, 1)
         if records == "full":
-            stats_list, recs = self._epoch_full(ys_segs, N, S, passes,
-                                                gen_s)
+            stats_list, recs = self._epoch_full(ys_segs, N, S, passes)
             if sh_spec is not None:
                 touched = np.zeros((N, self._table.n_levers), bool)
                 rows = np.arange(N)[:, None]
@@ -822,7 +818,7 @@ class DeviceEpisodeRunner:
         cfgr._last_fleet_windows = None   # host-loop cache is stale now
         return stats_list, recs
 
-    def _epoch_full(self, ys_segs, N, S, passes, gen_s):
+    def _epoch_full(self, ys_segs, N, S, passes):
         """Materialise a ``records="full"`` epoch by replaying
         ``_materialise`` per (update, pass) chunk — record order, §2.4.1
         replay order and chaos accounting match the sequential schedule
@@ -839,7 +835,8 @@ class DeviceEpisodeRunner:
                     outs = {k2: v[i, sl] for k2, v in ys.items()
                             if k2 not in ("pg_loss", "mean_return")}
                     configs = self._materialise(
-                        {"outs": outs, "S": S}, configs, recs, gen_s)
+                        {"outs": outs, "S": S}, configs, recs,
+                        i * passes + p)
                 stats_list.append(
                     {"pg_loss": float(ys["pg_loss"][i]),
                      "mean_return": float(ys["mean_return"][i]),
@@ -952,21 +949,23 @@ class DeviceEpisodeRunner:
             sh = (tuple(self._carry[pos:pos + 4])
                   if sh_spec is not None else ())
 
-        T, E = self._tick_budget()
-        exploit = cfgr.agent.exploit_ready(explore=explore)
-        greedy = bool(greedy or not explore)
-        pallas = bool(getattr(dev, "pallas", False))
-        slo_sig = ((float(cfgr.slo_ms), float(cfgr.slo_hinge_w),
-                    float(cfgr.slo_breach_w))
-                   if cfgr.reward_mode == "slo" else None)
-        skey = (S, T, E, self._sel_cols, exploit, greedy, cfgr.reward_mode,
-                float(cfgr.window_s), pallas,
-                self.mesh.size if self.mesh is not None else 0,
-                slo_sig, self._R_max, self._ft_dev is not None, sh_spec)
-        prog = self._program(skey, {"cc_pairs": self._cc_pairs,
-                                    "ranked_g": self._ranked_g})
+        with span("tune.dispatch.episode") as sp, \
+                warnings.catch_warnings():
+            T, E = self._tick_budget()
+            exploit = cfgr.agent.exploit_ready(explore=explore)
+            greedy = bool(greedy or not explore)
+            pallas = bool(getattr(dev, "pallas", False))
+            slo_sig = ((float(cfgr.slo_ms), float(cfgr.slo_hinge_w),
+                        float(cfgr.slo_breach_w))
+                       if cfgr.reward_mode == "slo" else None)
+            skey = (S, T, E, self._sel_cols, exploit, greedy,
+                    cfgr.reward_mode, float(cfgr.window_s), pallas,
+                    self.mesh.size if self.mesh is not None else 0,
+                    slo_sig, self._R_max, self._ft_dev is not None, sh_spec)
+            prog = self._program(skey, {"cc_pairs": self._cc_pairs,
+                                        "ranked_g": self._ranked_g})
 
-        with warnings.catch_warnings():
+            traces0 = sum(TRACE_COUNTS.values())
             # fresh-epoch inputs arrive host-committed; their donation only
             # becomes effective once the carried buffers chain device-side
             warnings.filterwarnings("ignore", message="Some donated buffers")
@@ -976,6 +975,9 @@ class DeviceEpisodeRunner:
                 self._kind_code, self._n_valid, self._reboot_f,
                 self._rejit_f, self._mc_arg, self._emitF,
                 self._ft_dev, self._delays, hist, *sh)
+            traced = sum(TRACE_COUNTS.values()) - traces0
+            sp.set_metadata(traced=traced)
+        count("tune.programs_traced", traced)
         self._carry = carry
         self._inflight.append({"outs": outs, "S": S})
         return {"states": outs["states"], "actions": outs["actions"],
@@ -985,6 +987,10 @@ class DeviceEpisodeRunner:
         """Host-side packing for the first batch of an epoch: re-pack the
         integerised lever table from the (possibly adapted) oracle, pack the
         workload table, borrow the engine's queueing state."""
+        with span("tune.pack"):
+            return self._pack_inputs()
+
+    def _pack_inputs(self) -> tuple:
         cfgr, env = self.cfgr, self.env
         dev = env._dev
 
@@ -998,22 +1004,28 @@ class DeviceEpisodeRunner:
         repack = self._table is None or disc_sig != self._disc_sig
         self._disc_sig = disc_sig
         if repack:
-            table = DeviceLeverTable.from_discretiser(cfgr.disc)
-            self._table = table
-            from repro.engine.fleet_jax import _bucket
-            B_pad = max(_bucket(table.max_bins, _BIN_BUCKETS), self._hw_B)
-            self._hw_B = B_pad
-            packed_tabs = build_packed_tables(table, pad_to=B_pad)
-            self._cc_pairs = tuple((k, li) for k, li, _ in packed_tabs)
-            self._tabs = {k: jnp.asarray(tab) for k, li, tab in packed_tabs}
-            self._kind_code = jnp.asarray(table.kind_code)
-            self._n_valid = jnp.asarray(table.n_valid)
-            self._reboot_f = jnp.asarray([1.0 if s.reboot else 0.0
-                                          for s in table.specs], jnp.float32)
-            self._rejit_f = jnp.asarray(
-                [1.0 if s.group in ("kernel", "memory", "parallel") else 0.0
-                 for s in table.specs], jnp.float32)
-            self._ranked_g = tuple(table.index_of[n] for n in cfgr.levers)
+            count("tune.repacks")
+            with span("tune.pack.repack"):
+                table = DeviceLeverTable.from_discretiser(cfgr.disc)
+                self._table = table
+                from repro.engine.fleet_jax import _bucket
+                B_pad = max(_bucket(table.max_bins, _BIN_BUCKETS),
+                            self._hw_B)
+                self._hw_B = B_pad
+                packed_tabs = build_packed_tables(table, pad_to=B_pad)
+                self._cc_pairs = tuple((k, li) for k, li, _ in packed_tabs)
+                self._tabs = {k: jnp.asarray(tab)
+                              for k, li, tab in packed_tabs}
+                self._kind_code = jnp.asarray(table.kind_code)
+                self._n_valid = jnp.asarray(table.n_valid)
+                self._reboot_f = jnp.asarray(
+                    [1.0 if s.reboot else 0.0 for s in table.specs],
+                    jnp.float32)
+                self._rejit_f = jnp.asarray(
+                    [1.0 if s.group in ("kernel", "memory", "parallel")
+                     else 0.0 for s in table.specs], jnp.float32)
+                self._ranked_g = tuple(table.index_of[n]
+                                       for n in cfgr.levers)
         table = self._table
         if self._wl_dev is None:
             tbl = pack_device_workloads(env.workloads)
@@ -1046,7 +1058,9 @@ class DeviceEpisodeRunner:
                 and np.array_equal(self._clock_mark, env.clock)):
             config_idx = self._config_idx
         else:
-            config_idx = jnp.asarray(table.index_configs(configs))
+            count("tune.index_rebuilds")
+            with span("tune.pack.index"):
+                config_idx = jnp.asarray(table.index_configs(configs))
             self._hist = None   # stale config history can't be replayed
             self._shield = None  # LKG indices refer to the old ladder
         self._bins_sig = sig
@@ -1127,9 +1141,9 @@ class DeviceEpisodeRunner:
         cfgr, env = self.cfgr, self.env
         inflight, self._inflight = self._inflight, []
         carry, self._carry = self._carry, None
-        jax.block_until_ready(inflight[-1]["outs"])
+        with span("tune.wait"):
+            jax.block_until_ready(inflight[-1]["outs"])
         self.last_wall_s = time.perf_counter() - self._epoch_t0
-        total_steps = sum(e["S"] for e in inflight) * env.n_clusters
         self.chaos.add_wall(self.last_wall_s)
 
         # ---- hand the queueing state back to the engine -------------------
@@ -1141,25 +1155,30 @@ class DeviceEpisodeRunner:
             self._hist = carry[9]
             pos = 10
         sh_spec = getattr(cfgr, "shield", None)
-        if sh_spec is not None:
-            self._shield = tuple(carry[pos:pos + 4])
-            self.shield.trust_radius = float(
-                np.asarray(self._shield[1]).mean())
-        env._dev.adopt_loop_state(backlog_f, sfree_f, clock_f)
-        env.reconfigs[:] = np.asarray(reconfigs_f, np.int64)
-        env.last_service[:] = np.asarray(last_service_f, np.float64)
-        rng_range = cfgr.encoder._range
-        rng_range.lo = np.asarray(lo_f, np.float64)
-        rng_range.hi = np.asarray(hi_f, np.float64)
+        pulled = [clock_f, reconfigs_f, last_service_f, lo_f, hi_f]
+        with span("tune.pull") as sp:
+            if sh_spec is not None:
+                self._shield = tuple(carry[pos:pos + 4])
+                self.shield.trust_radius = float(
+                    np.asarray(self._shield[1]).mean())
+                pulled.append(self._shield[1])
+            env._dev.adopt_loop_state(backlog_f, sfree_f, clock_f)
+            env.reconfigs[:] = np.asarray(reconfigs_f, np.int64)
+            env.last_service[:] = np.asarray(last_service_f, np.float64)
+            rng_range = cfgr.encoder._range
+            rng_range.lo = np.asarray(lo_f, np.float64)
+            rng_range.hi = np.asarray(hi_f, np.float64)
+            nbytes = sum(int(x.nbytes) for x in pulled)
+            sp.set_metadata(bytes=nbytes)
+        count("tune.pull_bytes", nbytes)
         self._per_node = per_node_f
         self._config_idx = config_idx_f
         self._clock_mark = env.clock.copy()
 
         configs = self._epoch_configs
         records: list = []
-        gen_s = self.last_wall_s / max(total_steps, 1)
-        for entry in inflight:
-            configs = self._materialise(entry, configs, records, gen_s)
+        for k, entry in enumerate(inflight):
+            configs = self._materialise(entry, configs, records, k)
         env.configs = configs
         env.invalidate()
         if sh_spec is not None:
@@ -1205,66 +1224,69 @@ class DeviceEpisodeRunner:
         self.env.invalidate()
 
     def _materialise(self, entry: dict, configs: list, records: list,
-                     gen_s: float) -> list:
-        """StepRecords + §2.4.1 bin replay for ONE batch; returns the
-        batch's final config dicts (the next chained batch starts there)."""
+                     batch: int) -> list:
+        """StepRecords + §2.4.1 bin replay for ONE batch (``batch``: its
+        place among the batches materialised together, which tags the
+        spans); returns the batch's final config dicts (the next chained
+        batch starts there)."""
         env, table = self.env, self._table
         outs, S = entry["outs"], entry["S"]
         N = env.n_clusters
-        # bulk device->host pulls, then C-speed list conversion: the record
-        # loop below touches every element once and python-float access via
-        # tolist() is ~5x cheaper than per-element np scalar indexing
-        lever = np.asarray(outs["lever"])            # (N, S)
-        new_bin = np.asarray(outs["bin"])
-        lever_l, bin_l = lever.tolist(), new_bin.tolist()
-        rewards_a = np.asarray(outs["rewards"])
-        p99_a = np.asarray(outs["p99_ms"])
-        self.chaos.record_batch(
-            rewards_a, p99_a,
-            np.asarray(outs["breach_frac"]) if "breach_frac" in outs else None,
-            slo_ms=self.cfgr.slo_ms)
-        if "shield_fallback" in outs:
-            self.shield.clamped_actions += int(
-                np.asarray(outs["shield_clamped"]).sum())
-            self.shield.fallbacks += int(
-                np.asarray(outs["shield_fallback"]).sum())
-            # one exhaustion per (cluster, episode) whose budget ran dry
-            self.shield.budget_exhaustions += int(
-                np.asarray(outs["budget_out"]).any(axis=1).sum())
-        rewards = rewards_a.tolist()
-        p99 = p99_a.tolist()
-        clock_s = np.asarray(outs["clock_s"]).tolist()
-        load_s = np.asarray(outs["load_s"]).tolist()
-        stab_s = np.asarray(outs["stab_s"]).tolist()
-        directions = (1 - 2 * (np.asarray(outs["actions"]) % 2)).tolist()
+        # bulk device->host pulls of what the host reads, all up front
+        with span("tune.pull", batch=batch) as sp:
+            o = {k: np.asarray(outs[k]) for k in _RECORD_OUTS if k in outs}
+            nbytes = sum(a.nbytes for a in o.values())
+            sp.set_metadata(bytes=nbytes)
+        count("tune.pull_bytes", nbytes)
         from repro.core.configurator import StepRecord
 
-        # the action set only reaches a few levers × bins: memoise the decode
-        # instead of 5k+ value_of calls per batch
-        val_cache: dict = {}
-        names = table.names
-        final_configs = []
-        for i in range(N):
-            cfg = configs[i]
-            lv_i, bn_i, dir_i = lever_l[i], bin_l[i], directions[i]
-            rw_i, p_i, ck_i = rewards[i], p99[i], clock_s[i]
-            ld_i, st_i = load_s[i], stab_s[i]
-            for t in range(S):
-                li, b = lv_i[t], bn_i[t]
-                val = val_cache.get((li, b))
-                if val is None:
-                    val = val_cache[(li, b)] = table.value_of(li, b)
-                cfg = dict(cfg)
-                cfg[names[li]] = val
-                records.append(StepRecord(
-                    lever=names[li], direction=dir_i[t],
-                    config=cfg, reward=rw_i[t],
-                    p99_ms=p_i[t], clock_s=ck_i[t],
-                    phases={"generation_s": gen_s,
-                            "loading_s": ld_i[t],
-                            "stabilisation_s": st_i[t],
-                            "update_s": 0.0}))
-            final_configs.append(dict(cfg))
+        with span("tune.records", batch=batch, records=N * S):
+            lever, new_bin = o["lever"], o["bin"]            # (N, S)
+            self.chaos.record_batch(o["rewards"], o["p99_ms"],
+                                    o.get("breach_frac"),
+                                    slo_ms=self.cfgr.slo_ms)
+            if "shield_fallback" in o:
+                self.shield.clamped_actions += int(o["shield_clamped"].sum())
+                self.shield.fallbacks += int(o["shield_fallback"].sum())
+                # one exhaustion per (cluster, episode) whose budget ran dry
+                self.shield.budget_exhaustions += int(
+                    o["budget_out"].any(axis=1).sum())
+            # C-speed list conversion: the record loop below touches every
+            # element once and python-float access via tolist() is ~5x
+            # cheaper than per-element np scalar indexing
+            lever_l, bin_l = lever.tolist(), new_bin.tolist()
+            rewards = o["rewards"].tolist()
+            p99 = o["p99_ms"].tolist()
+            clock_s = o["clock_s"].tolist()
+            load_s = o["load_s"].tolist()
+            stab_s = o["stab_s"].tolist()
+            directions = (1 - 2 * (o["actions"] % 2)).tolist()
+
+            # the action set only reaches a few levers × bins: memoise the
+            # decode instead of 5k+ value_of calls per batch
+            val_cache: dict = {}
+            names = table.names
+            final_configs = []
+            for i in range(N):
+                cfg = configs[i]
+                lv_i, bn_i, dir_i = lever_l[i], bin_l[i], directions[i]
+                rw_i, p_i, ck_i = rewards[i], p99[i], clock_s[i]
+                ld_i, st_i = load_s[i], stab_s[i]
+                for t in range(S):
+                    li, b = lv_i[t], bn_i[t]
+                    val = val_cache.get((li, b))
+                    if val is None:
+                        val = val_cache[(li, b)] = table.value_of(li, b)
+                    cfg = dict(cfg)
+                    cfg[names[li]] = val
+                    records.append(StepRecord(
+                        lever=names[li], direction=dir_i[t],
+                        config=cfg, reward=rw_i[t],
+                        p99_ms=p_i[t], clock_s=ck_i[t],
+                        phases={"loading_s": ld_i[t],
+                                "stabilisation_s": st_i[t]}))
+                final_configs.append(dict(cfg))
+        count("tune.records_built", N * S)
 
         # ---- replay the chosen bins into the adaptive oracle ---------------
         # (paper-§2.4.1 split/extend/merge runs host-side BETWEEN batches;
@@ -1274,10 +1296,16 @@ class DeviceEpisodeRunner:
         # to the exact per-assignment loop whenever a rule could fire
         # mid-batch) instead of N·S python record() calls.
         bins = self.cfgr.disc.bins
-        lever_sm = lever.T.ravel()        # (S·N,) step-major
-        bin_sm = new_bin.T.ravel()
-        for li in np.unique(lever_sm):
-            dyn = bins.get(names[li])
-            if dyn is not None:
-                dyn.record_many(bin_sm[lever_sm == li])
+        replayed = 0
+        with span("tune.replay", batch=batch) as sp:
+            lever_sm = lever.T.ravel()        # (S·N,) step-major
+            bin_sm = new_bin.T.ravel()
+            for li in np.unique(lever_sm):
+                dyn = bins.get(names[li])
+                if dyn is not None:
+                    chosen = bin_sm[lever_sm == li]
+                    dyn.record_many(chosen)
+                    replayed += chosen.size
+            sp.set_metadata(assignments=replayed)
+        count("tune.replayed", replayed)
         return final_configs

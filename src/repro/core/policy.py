@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.monitoring.spans import count, span
 from repro.optim import rmsprop
 
 PyTree = Any
@@ -324,15 +325,20 @@ class ReinforceAgent:
         scalars. The §11 double-buffer hook: the fused training loop runs
         its host-side record materialisation and §2.4.1 bin replay between
         dispatch and pull, overlapping the device update."""
-        states = jnp.asarray(states, jnp.float32)
-        actions = jnp.asarray(actions, jnp.int32)
-        rewards = jnp.asarray(rewards, jnp.float32)
-        if mask is None:
-            mask = jnp.ones(actions.shape, jnp.float32)
-        else:
-            mask = jnp.asarray(mask, jnp.float32)
-        self.params, self.opt_state, loss, first = self._update_jit(
-            self.params, self.opt_state, states, actions, rewards, mask)
+        traces0 = UPDATE_TRACE_COUNT[0]
+        with span("tune.dispatch.update") as sp:
+            states = jnp.asarray(states, jnp.float32)
+            actions = jnp.asarray(actions, jnp.int32)
+            rewards = jnp.asarray(rewards, jnp.float32)
+            if mask is None:
+                mask = jnp.ones(actions.shape, jnp.float32)
+            else:
+                mask = jnp.asarray(mask, jnp.float32)
+            self.params, self.opt_state, loss, first = self._update_jit(
+                self.params, self.opt_state, states, actions, rewards, mask)
+            traced = UPDATE_TRACE_COUNT[0] - traces0
+            sp.set_metadata(traced=traced)
+        count("tune.programs_traced", traced)
         self.n_updates += 1
         episodes = int(actions.shape[0])
 

@@ -94,7 +94,7 @@ def main(argv=None):
     from repro.utils import enable_compile_cache
 
     enable_compile_cache()
-    from repro.monitoring import flush_guard
+    from repro.monitoring import flush_guard, spans
     from repro.serve import ServeController
 
     out = Path(args.out)
@@ -142,7 +142,7 @@ def main(argv=None):
         text = ctl.counters.prometheus_text()
         if args.safe:
             text += ctl.cfgr.shield_counters.prometheus_text()
-        return text
+        return text + spans.prometheus_text()
 
     def cb(s):
         print(f"[cycle {s['cycle']:>3}] {s['decision']:<8} "

@@ -172,7 +172,7 @@ def main(argv=None):
               f"min {np.min([r.p99_ms for r in last]):.0f} ms  "
               f"return {stats['mean_return']:.2f}")
 
-    from repro.monitoring import ChaosCounters, flush_guard
+    from repro.monitoring import ChaosCounters, flush_guard, spans
 
     out = Path(args.out)
     out.mkdir(parents=True, exist_ok=True)
@@ -183,7 +183,7 @@ def main(argv=None):
         text = chaos.prometheus_text()
         if args.safe:
             text += cfgr.shield_counters.prometheus_text()
-        return text
+        return text + spans.prometheus_text()
 
     # the guard (shared with launch/serve.py) remaps SIGTERM to
     # KeyboardInterrupt and writes the dump in its finally — a Ctrl-C'd or

@@ -11,6 +11,7 @@ from repro.monitoring.metrics import (
     flush_guard,
     retrace_counts,
 )
+from repro.monitoring.spans import SpanTotals, count, span, step
 
 __all__ = [
     "DRIVER_METRICS",
@@ -20,8 +21,12 @@ __all__ = [
     "ChaosCounters",
     "MetricDef",
     "ServeCounters",
+    "SpanTotals",
     "TimeSeriesStore",
     "build_registry",
     "flush_guard",
+    "count",
     "retrace_counts",
+    "span",
+    "step",
 ]

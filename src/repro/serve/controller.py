@@ -40,6 +40,7 @@ import numpy as np
 from repro.core.configurator import Configurator
 from repro.engine import FleetEnv
 from repro.monitoring.metrics import ServeCounters, retrace_counts
+from repro.monitoring.spans import span, step
 from repro.serve.canary import CanaryGate
 from repro.serve.history import EpisodeStore, _jsonable, workload_features
 
@@ -177,77 +178,88 @@ class ServeController:
         t0 = time.perf_counter()
         self.cycle += 1
         c = self.counters
+        with step("serve.cycle", self.cycle):
+            # ---- shadow: train + surface this cycle's candidate -----------
+            with span("serve.shadow"):
+                self._reset_queues(self.shadow_env)
+                if self.epoch_k > 1:
+                    n0 = len(self.cfgr.history)
+                    stats_list = self.cfgr.run_epoch(self.epoch_k,
+                                                     records="full")
+                    stats = dict(stats_list[-1]) if stats_list else {}
+                    recs = self.cfgr.history[n0:]
+                else:
+                    stats = self.cfgr.run_cycle()
+                    recs = stats.pop("records")
+                c.inc("shadow_windows", len(recs))
+                best = max(recs, key=lambda r: r.reward) if recs else None
+                if best is not None:
+                    self.history.append(
+                        cycle=self.cycle, role="shadow",
+                        workload=workload_features(
+                            self.shadow_env.workloads[0],
+                            float(self.shadow_env.clock[0])),
+                        config=dict(best.config), reward=float(best.reward),
+                        p99_ms=float(best.p99_ms),
+                        clock_s=float(best.clock_s))
+            if self.gate.challenger is None and recs:
+                with span("serve.challenger"):
+                    self._adopt_challenger(recs)
 
-        # ---- shadow: train + surface this cycle's candidate ---------------
-        self._reset_queues(self.shadow_env)
-        if self.epoch_k > 1:
-            n0 = len(self.cfgr.history)
-            stats_list = self.cfgr.run_epoch(self.epoch_k, records="full")
-            stats = dict(stats_list[-1]) if stats_list else {}
-            recs = self.cfgr.history[n0:]
-        else:
-            stats = self.cfgr.run_cycle()
-            recs = stats.pop("records")
-        c.inc("shadow_windows", len(recs))
-        best = max(recs, key=lambda r: r.reward) if recs else None
-        if best is not None:
-            self.history.append(
-                cycle=self.cycle, role="shadow",
-                workload=workload_features(self.shadow_env.workloads[0],
-                                           float(self.shadow_env.clock[0])),
-                config=dict(best.config), reward=float(best.reward),
-                p99_ms=float(best.p99_ms), clock_s=float(best.clock_s))
-        if self.gate.challenger is None and recs:
-            self._adopt_challenger(recs)
+            # ---- §16 breach-budget trip: shadow exhausted its per-episode
+            # breach budget this cycle → demote the queued challenger
+            # without spending a canary cycle on it, and contract the
+            # shield's trust region to its floor (expansion re-earned by
+            # clean windows)
+            budget_tripped = False
+            if self.safe:
+                bx = self.cfgr.shield_counters.budget_exhaustions
+                budget_tripped = bx > self._budget_seen
+                self._budget_seen = bx
+                if budget_tripped:
+                    self.cfgr.contract_shield()
+                    if self.gate.challenger is not None:
+                        self.gate.force_demote(cycle=self.cycle,
+                                               reason="breach_budget")
+                        c.inc("demotions")
 
-        # ---- §16 breach-budget trip: shadow exhausted its per-episode
-        # breach budget this cycle → demote the queued challenger without
-        # spending a canary cycle on it, and contract the shield's trust
-        # region to its floor (expansion re-earned by clean windows)
-        budget_tripped = False
-        if self.safe:
-            bx = self.cfgr.shield_counters.budget_exhaustions
-            budget_tripped = bx > self._budget_seen
-            self._budget_seen = bx
-            if budget_tripped:
-                self.cfgr.contract_shield()
-                if self.gate.challenger is not None:
-                    self.gate.force_demote(cycle=self.cycle,
-                                           reason="breach_budget")
-                    c.inc("demotions")
+            # ---- canary: paired challenger-vs-incumbent evaluation --------
+            decision = "budget_demote" if budget_tripped else "shadow"
+            cand_r = inc_r = None
+            if self.gate.challenger is not None:
+                challenger = dict(self.gate.challenger)
+                with span("serve.canary"):
+                    cand_r, inc_r, breached = self._canary_eval(challenger)
+                with span("serve.gate") as sp:
+                    decision = self.gate.decide(cand_r, inc_r, breached,
+                                                cycle=self.cycle)
+                    sp.set_metadata(decision=decision)
+                    self.history.append(
+                        cycle=self.cycle, role="canary",
+                        workload=workload_features(
+                            self.canary_env.workloads[0],
+                            float(self.canary_env.clock[0])),
+                        config=challenger, reward=cand_r, p99_ms=float(
+                            c.last_canary_p99_ms), clock_s=float(
+                            self.canary_env.clock[0]), breached=breached)
+                    if decision == "promote":
+                        self._promote(challenger, cand_r)
+                    elif decision == "rollback":
+                        self._rollback()
+                    elif decision == "demote":
+                        c.inc("demotions")
+                    else:
+                        c.inc("holds")
 
-        # ---- canary: paired challenger-vs-incumbent evaluation ------------
-        decision = "budget_demote" if budget_tripped else "shadow"
-        cand_r = inc_r = None
-        if self.gate.challenger is not None:
-            challenger = dict(self.gate.challenger)
-            cand_r, inc_r, breached = self._canary_eval(challenger)
-            decision = self.gate.decide(cand_r, inc_r, breached,
-                                        cycle=self.cycle)
-            self.history.append(
-                cycle=self.cycle, role="canary",
-                workload=workload_features(self.canary_env.workloads[0],
-                                           float(self.canary_env.clock[0])),
-                config=challenger, reward=cand_r, p99_ms=float(
-                    c.last_canary_p99_ms), clock_s=float(
-                    self.canary_env.clock[0]), breached=breached)
-            if decision == "promote":
-                self._promote(challenger, cand_r)
-            elif decision == "rollback":
-                self._rollback()
-            elif decision == "demote":
-                c.inc("demotions")
-            else:
-                c.inc("holds")
+            # ---- live: one serving window under the incumbent --------------
+            with span("serve.live"):
+                live = self._live_window()
 
-        # ---- live: one serving window under the incumbent ------------------
-        live = self._live_window()
-
-        c.inc("cycles")
-        # sample the process-wide trace total as a gauge: flat cycle-over-
-        # cycle in steady state, climbing = the device programs are being
-        # recompiled (the dashboard view of the §13 no-retrace pin)
-        c.retraces = retrace_counts()
+            c.inc("cycles")
+            # sample the process-wide trace total as a gauge: flat cycle-
+            # over-cycle in steady state, climbing = the device programs are
+            # being recompiled (the dashboard view of the §13 no-retrace pin)
+            c.retraces = retrace_counts()
         wall = time.perf_counter() - t0
         c.add_wall(wall)
         return {"cycle": self.cycle, "decision": decision,
@@ -592,7 +604,9 @@ class ServeController:
         every promotion; callable any time (e.g. a periodic cadence)."""
         assert self.store is not None, "construct with checkpoint_dir="
         step = int(step if step is not None else self.cycle)
-        self.store.save(step, self._state_tree(), extra=self._state_extra())
+        with span("serve.checkpoint"):
+            self.store.save(step, self._state_tree(),
+                            extra=self._state_extra())
         return step
 
     def restore(self, store=None, *, step: Optional[int] = None) -> int:

@@ -22,6 +22,8 @@ from typing import Optional
 
 import numpy as np
 
+from repro.monitoring.spans import count, span
+
 
 def _jsonable(o):
     """Recursively convert numpy scalars/arrays so rows survive json.dumps."""
@@ -67,15 +69,18 @@ class EpisodeStore:
     def append(self, *, cycle: int, role: str, workload: dict, config: dict,
                reward: float, p99_ms: float, clock_s: float,
                breached: bool = False) -> dict:
-        row = _jsonable({"cycle": int(cycle), "role": role,
-                         "clock_s": float(clock_s), "workload": workload,
-                         "config": config, "reward": float(reward),
-                         "p99_ms": float(p99_ms), "breached": bool(breached)})
-        self._rows.append(row)
-        if self.path is not None:
-            with self.path.open("a") as f:
-                f.write(json.dumps(row) + "\n")
-                f.flush()
+        with span("serve.history"):
+            row = _jsonable({"cycle": int(cycle), "role": role,
+                             "clock_s": float(clock_s), "workload": workload,
+                             "config": config, "reward": float(reward),
+                             "p99_ms": float(p99_ms),
+                             "breached": bool(breached)})
+            self._rows.append(row)
+            if self.path is not None:
+                with self.path.open("a") as f:
+                    f.write(json.dumps(row) + "\n")
+                    f.flush()
+        count("serve.history_rows")
         return row
 
     def rows(self, *, role: Optional[str] = None) -> list[dict]:

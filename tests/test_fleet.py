@@ -143,8 +143,7 @@ def test_fleet_configurator_runs_parallel_episodes():
     assert stats["steps"] == 8             # 4 episodes x 2 steps
     assert len(cfgr.history) == 8
     ph = cfgr.history[-1].phases
-    assert set(ph) == {"generation_s", "loading_s", "stabilisation_s",
-                       "update_s"}
+    assert set(ph) == {"loading_s", "stabilisation_s"}
 
 
 def test_act_batch_matches_action_space():
