@@ -14,6 +14,10 @@ spans (``repro.monitoring.spans``).
 """
 from __future__ import annotations
 
+import bisect
+import itertools
+import operator
+from collections import abc
 from dataclasses import dataclass, field
 from typing import Optional, Protocol, Sequence
 
@@ -23,7 +27,7 @@ from repro.core.discretize import (DeviceLeverTable, LeverDiscretiser,
                                    LeverSpec, ShieldSpec, shield_update)
 from repro.core.heatmap import HeatmapEncoder, HeatmapSpec
 from repro.core.policy import ReinforceAgent, Trajectory
-from repro.monitoring.spans import span, step
+from repro.monitoring.spans import count, span, step
 
 
 class MetricsWindow(Protocol):
@@ -88,7 +92,7 @@ def is_fleet_env(env) -> bool:
     return getattr(env, "n_clusters", 0) >= 1 and hasattr(env, "apply_configs")
 
 
-@dataclass
+@dataclass(eq=False)
 class StepRecord:
     lever: str
     direction: int
@@ -97,6 +101,201 @@ class StepRecord:
     p99_ms: float
     clock_s: float
     phases: dict  # simulated loading/stabilisation seconds
+
+    def __eq__(self, other):
+        # field by field, whatever the StepRecord class: a record read from
+        # a StepBatch equals the one the per-step loop would have built
+        if not isinstance(other, StepRecord):
+            return NotImplemented
+        return (self.lever == other.lever
+                and self.direction == other.direction
+                and self.reward == other.reward
+                and self.p99_ms == other.p99_ms
+                and self.clock_s == other.clock_s
+                and self.phases == other.phases
+                and self.config == other.config)
+
+
+class StepBatch:
+    """One fused episode batch of N clusters × S steps, stored column-wise
+    (DESIGN.md §10): the pulled (N, S) step arrays, each step's decoded
+    lever value (an index into ``values``, decoded with the lever table of
+    the batch's own materialisation — later §2.4.1 replays move the bins)
+    and the N configs the batch started from, which must never be mutated
+    in place afterwards. Step ``j = i·S + t`` (cluster-major, the host
+    loop's order) reads as a ``StepRecord`` whose ``config`` — cluster i's
+    starting config with the values of steps 0..t applied in order — and
+    ``phases`` are built only when read."""
+
+    def __init__(self, names, start, lever, bin_idx, direction, rewards,
+                 p99_ms, clock_s, load_s, stab_s, val_idx, values):
+        self.names, self.values = names, values
+        self.start = tuple(start)
+        self.S = int(np.shape(lever)[1])
+
+        def col(a):   # owned flat copy, whatever buffer the pull handed us
+            return np.array(a).reshape(-1)
+
+        self.lever, self.bin, self.val_idx = col(lever), col(bin_idx), \
+            col(val_idx)
+        self.direction, self.rewards, self.p99_ms = col(direction), \
+            col(rewards), col(p99_ms)
+        self.clock_s, self.load_s, self.stab_s = col(clock_s), col(load_s), \
+            col(stab_s)
+
+    def __len__(self) -> int:
+        return self.lever.size
+
+    def __getitem__(self, j: int) -> StepRecord:
+        return _BatchRecord(self, j, self.names[self.lever[j]],
+                            self.direction[j].item(), self.rewards[j].item(),
+                            self.p99_ms[j].item(), self.clock_s[j].item())
+
+    def records(self, lo: int, hi: int):
+        """Iterate steps ``lo:hi`` (one bulk conversion per column)."""
+        names = self.names
+        cols = zip(range(lo, hi), self.lever[lo:hi].tolist(),
+                   self.direction[lo:hi].tolist(),
+                   self.rewards[lo:hi].tolist(), self.p99_ms[lo:hi].tolist(),
+                   self.clock_s[lo:hi].tolist())
+        for j, li, d, r, p, c in cols:
+            yield _BatchRecord(self, j, names[li], d, r, p, c)
+
+    def config_at(self, j: int) -> dict:
+        """Step j's config, built from its cluster's starting config."""
+        lo = j - j % self.S
+        cfg = dict(self.start[lo // self.S])
+        names, values = self.names, self.values
+        for li, vi in zip(self.lever[lo:j + 1].tolist(),
+                          self.val_idx[lo:j + 1].tolist()):
+            cfg[names[li]] = values[vi]
+        count("tune.record_configs_built")
+        return cfg
+
+    def phases_at(self, j: int) -> dict:
+        return {"loading_s": self.load_s[j].item(),
+                "stabilisation_s": self.stab_s[j].item()}
+
+    def final_configs(self) -> list[dict]:
+        """The N configs after each cluster's last step: the next chained
+        batch and ``env.configs`` start from them."""
+        names, values, S = self.names, self.values, self.S
+        lever, val_idx = self.lever.tolist(), self.val_idx.tolist()
+        out = []
+        for i, start in enumerate(self.start):
+            cfg = dict(start)
+            for j in range(i * S, (i + 1) * S):
+                cfg[names[lever[j]]] = values[val_idx[j]]
+            out.append(cfg)
+        return out
+
+
+class _BatchRecord(StepRecord):
+    """A ``StepBatch`` step: ``config`` and ``phases`` are built on first
+    read (and kept on this record object)."""
+
+    def __init__(self, batch: StepBatch, j: int, lever: str, direction: int,
+                 reward: float, p99_ms: float, clock_s: float):
+        self.lever, self.direction = lever, direction
+        self.reward, self.p99_ms, self.clock_s = reward, p99_ms, clock_s
+        self._at = (batch, j)
+
+    @property
+    def config(self) -> dict:
+        cfg = self.__dict__.get("_config")
+        if cfg is None:
+            batch, j = self._at
+            cfg = self._config = batch.config_at(j)
+        return cfg
+
+    @property
+    def phases(self) -> dict:
+        ph = self.__dict__.get("_phases")
+        if ph is None:
+            batch, j = self._at
+            ph = self._phases = batch.phases_at(j)
+        return ph
+
+
+class StepHistory(abc.Sequence):
+    """``Configurator.history``: every step record in the order it was
+    added. Holds segments of ``StepBatch``es (the fused loop's columnar
+    batches) and of plain ``StepRecord`` lists (the host loop's); indexing
+    and iteration read through them, and a slice with step 1 is a view
+    over the same segments, not a copy of any record or config."""
+
+    def __init__(self, records=()):
+        self._segs: list = []   # (source, lo, hi): source[lo:hi] in order
+        self._ends: list = []   # running end offset of each segment
+        self.extend(records)
+
+    def _add(self, src, lo: int, hi: int) -> None:
+        if hi > lo:
+            self._segs.append((src, lo, hi))
+            self._ends.append(len(self) + hi - lo)
+
+    def extend(self, records) -> None:
+        if isinstance(records, StepHistory):
+            for seg in list(records._segs):
+                self._add(*seg)
+        elif isinstance(records, StepBatch):
+            self._add(records, 0, len(records))
+        else:
+            recs = list(records)
+            self._add(recs, 0, len(recs))
+
+    def __len__(self) -> int:
+        return self._ends[-1] if self._ends else 0
+
+    def __getitem__(self, k):
+        if isinstance(k, slice):
+            lo, hi, st = k.indices(len(self))
+            if st != 1:
+                return [self[j] for j in range(lo, hi, st)]
+            return self._view(lo, hi)
+        n = len(self)
+        j = operator.index(k)
+        if j < 0:
+            j += n
+        if not 0 <= j < n:
+            raise IndexError("StepHistory index out of range")
+        s = bisect.bisect_right(self._ends, j)
+        src, lo, hi = self._segs[s]
+        return src[lo + j - (self._ends[s] - (hi - lo))]
+
+    def _view(self, lo: int, hi: int) -> "StepHistory":
+        out = StepHistory()
+        s = bisect.bisect_right(self._ends, lo)
+        while lo < hi and s < len(self._segs):
+            src, a, b = self._segs[s]
+            first = self._ends[s] - (b - a)      # global index of src[a]
+            out._add(src, a + lo - first, a + min(hi, self._ends[s]) - first)
+            lo = self._ends[s]
+            s += 1
+        return out
+
+    def __iter__(self):
+        for src, lo, hi in self._segs:
+            if isinstance(src, StepBatch):
+                yield from src.records(lo, hi)
+            else:
+                yield from itertools.islice(src, lo, hi)
+
+    def __add__(self, other) -> "StepHistory":
+        out = StepHistory(self)
+        out.extend(other)
+        return out
+
+    def __eq__(self, other):
+        if not isinstance(other, (StepHistory, list, tuple)):
+            return NotImplemented
+        return len(self) == len(other) and all(
+            a == b for a, b in zip(self, other))
+
+    __hash__ = None
+
+    def __repr__(self) -> str:
+        return f"StepHistory({len(self)} records)"
 
 
 @dataclass
@@ -222,7 +421,7 @@ class Configurator:
         from repro.monitoring.metrics import ShieldCounters
         self.shield_counters = ShieldCounters()
         self._host_shield = None   # numpy twin carry (sig, lkg, radius, ...)
-        self.history: list[StepRecord] = []
+        self.history = StepHistory()
         self._last_window: Optional[MetricsWindow] = None
         self._last_fleet_windows: Optional[list] = None
         try:  # selected-metric columns in registry order (dense encodes)
@@ -580,7 +779,7 @@ class Configurator:
         stats["records"] = self.history[n0:]
         return stats
 
-    def tune(self, n_updates: int, *, callback=None) -> list[StepRecord]:
+    def tune(self, n_updates: int, *, callback=None) -> StepHistory:
         for i in range(n_updates):
             stats = self.run_update()
             if callback:
@@ -588,7 +787,7 @@ class Configurator:
         return self.history
 
     def tune_pipelined(self, n_updates: int, *, depth: int = 2,
-                       callback=None) -> list[StepRecord]:
+                       callback=None) -> StepHistory:
         """``tune`` with a depth-``depth`` pipelined actor/learner
         (DESIGN.md §14): update k's jitted program runs while batch k+1's
         episode scan explores — device-to-device handoff of params and
@@ -644,7 +843,7 @@ class Configurator:
 
     def tune_megascan(self, n_updates: int, *, k: int = 8,
                       records: str = "full",
-                      callback=None) -> list[StepRecord]:
+                      callback=None) -> StepHistory:
         """``tune`` over epoch mega-scans (DESIGN.md §15): ``n_updates``
         outer iterations dispatched as ⌈n/k⌉ fused K-update epochs instead
         of n separate program pairs. The callback fires per update, after

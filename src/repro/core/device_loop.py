@@ -33,7 +33,8 @@ batch (S steps × N parallel episodes) into a single jitted device program:
 The program returns the full ``(N, S)`` states/actions/rewards batch (for
 ``ReinforceAgent.update_batch`` — the second and last device program of an
 outer iteration) plus the per-step bookkeeping (lever, bin, load, stab, p99)
-from which ``StepRecord``s are materialised ONCE per episode batch.
+which the host stores ONCE per episode batch, column-wise (a ``StepBatch``
+in the ``StepHistory``; a record's config is built only when read).
 
 Division of labour with the host oracle (DESIGN.md §10): the dict-based
 ``LeverDiscretiser`` stays authoritative for §2.4.1 *adaptation* — after
@@ -101,6 +102,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.core.configurator import StepBatch, StepHistory
 from repro.core.discretize import (MAX_SPLIT_BINS, DeviceLeverTable,
                                    shield_update)
 from repro.core.heatmap import node_grid_shape
@@ -609,7 +611,7 @@ class DeviceEpisodeRunner:
         """One fused episode batch, synchronously. Returns ``(batch,
         records)`` where ``batch`` holds the device-resident (N, S)
         states/actions/rewards for ``ReinforceAgent.update_batch`` and
-        ``records`` are the host-materialised ``StepRecord``s
+        ``records`` is a ``StepHistory`` of the batch's step records
         (cluster-major, matching the per-step host loop's ordering)."""
         batch = self.run_async(explore=explore, greedy=greedy)
         return batch, self.finalize()
@@ -665,7 +667,7 @@ class DeviceEpisodeRunner:
         if updates <= 0:
             return [], []
         if depth <= 1:
-            out, recs = [], []
+            out, recs = [], StepHistory()
             for _ in range(updates):
                 stats, records = self.run_cycle(passes=passes)
                 out.append(stats)
@@ -826,7 +828,7 @@ class DeviceEpisodeRunner:
         env = self.env
         configs = self._epoch_configs
         stats_list: list = []
-        recs: list = []
+        recs = StepHistory()
         for k_seg, ys in ys_segs:
             ys = {k2: np.asarray(v) for k2, v in ys.items()}
             for i in range(k_seg):
@@ -1131,13 +1133,13 @@ class DeviceEpisodeRunner:
                 jnp.asarray(rng_range.hi, jnp.float32), per_node)
 
     # -------------------------------------------------------------- finalize
-    def finalize(self) -> list:
+    def finalize(self) -> StepHistory:
         """Block on the epoch's dispatched batches, hand the queueing state
-        back to the engine, materialise every batch's ``StepRecord``s and
-        replay the chosen bins into the adaptive oracle (§2.4.1, batch
-        order). Returns the records, cluster-major per batch."""
+        back to the engine, store every batch's step records and replay
+        the chosen bins into the adaptive oracle (§2.4.1, batch order).
+        Returns the records, cluster-major per batch."""
         if not self._inflight:
-            return []
+            return StepHistory()
         cfgr, env = self.cfgr, self.env
         inflight, self._inflight = self._inflight, []
         carry, self._carry = self._carry, None
@@ -1176,7 +1178,7 @@ class DeviceEpisodeRunner:
         self._clock_mark = env.clock.copy()
 
         configs = self._epoch_configs
-        records: list = []
+        records = StepHistory()
         for k, entry in enumerate(inflight):
             configs = self._materialise(entry, configs, records, k)
         env.configs = configs
@@ -1223,9 +1225,10 @@ class DeviceEpisodeRunner:
         self.env.configs = configs
         self.env.invalidate()
 
-    def _materialise(self, entry: dict, configs: list, records: list,
-                     batch: int) -> list:
-        """StepRecords + §2.4.1 bin replay for ONE batch (``batch``: its
+    def _materialise(self, entry: dict, configs: list,
+                     records: StepHistory, batch: int) -> list:
+        """Step records (one ``StepBatch`` appended to ``records``) +
+        §2.4.1 bin replay for ONE batch (``batch``: its
         place among the batches materialised together, which tags the
         spans); returns the batch's final config dicts (the next chained
         batch starts there)."""
@@ -1238,8 +1241,6 @@ class DeviceEpisodeRunner:
             nbytes = sum(a.nbytes for a in o.values())
             sp.set_metadata(bytes=nbytes)
         count("tune.pull_bytes", nbytes)
-        from repro.core.configurator import StepRecord
-
         with span("tune.records", batch=batch, records=N * S):
             lever, new_bin = o["lever"], o["bin"]            # (N, S)
             self.chaos.record_batch(o["rewards"], o["p99_ms"],
@@ -1251,42 +1252,28 @@ class DeviceEpisodeRunner:
                 # one exhaustion per (cluster, episode) whose budget ran dry
                 self.shield.budget_exhaustions += int(
                     o["budget_out"].any(axis=1).sum())
-            # C-speed list conversion: the record loop below touches every
-            # element once and python-float access via tolist() is ~5x
-            # cheaper than per-element np scalar indexing
-            lever_l, bin_l = lever.tolist(), new_bin.tolist()
-            rewards = o["rewards"].tolist()
-            p99 = o["p99_ms"].tolist()
-            clock_s = o["clock_s"].tolist()
-            load_s = o["load_s"].tolist()
-            stab_s = o["stab_s"].tolist()
-            directions = (1 - 2 * (o["actions"] % 2)).tolist()
-
-            # the action set only reaches a few levers × bins: memoise the
-            # decode instead of 5k+ value_of calls per batch
-            val_cache: dict = {}
-            names = table.names
-            final_configs = []
-            for i in range(N):
-                cfg = configs[i]
-                lv_i, bn_i, dir_i = lever_l[i], bin_l[i], directions[i]
-                rw_i, p_i, ck_i = rewards[i], p99[i], clock_s[i]
-                ld_i, st_i = load_s[i], stab_s[i]
-                for t in range(S):
-                    li, b = lv_i[t], bn_i[t]
-                    val = val_cache.get((li, b))
-                    if val is None:
-                        val = val_cache[(li, b)] = table.value_of(li, b)
-                    cfg = dict(cfg)
-                    cfg[names[li]] = val
-                    records.append(StepRecord(
-                        lever=names[li], direction=dir_i[t],
-                        config=cfg, reward=rw_i[t],
-                        p99_ms=p_i[t], clock_s=ck_i[t],
-                        phases={"loading_s": ld_i[t],
-                                "stabilisation_s": st_i[t]}))
-                final_configs.append(dict(cfg))
+            # the action set only reaches a few levers × bins: decode each
+            # distinct (lever, bin) once, NOW — the replay below moves the
+            # bins, so a later decode would give other values
+            b0 = int(new_bin.min())
+            nb = int(new_bin.max()) - b0 + 1
+            keys, val_idx = np.unique(
+                (lever.astype(np.int64) * nb + (new_bin - b0)).reshape(-1),
+                return_inverse=True)
+            values = []
+            for key in keys.tolist():
+                li, b = divmod(key, nb)
+                values.append(table.value_of(li, b + b0))
+            # stored column-wise; a record's config is built when read
+            steps = StepBatch(table.names, configs, lever, new_bin,
+                              1 - 2 * (o["actions"] % 2), o["rewards"],
+                              o["p99_ms"], o["clock_s"], o["load_s"],
+                              o["stab_s"], val_idx.reshape(N, S), values)
+            records.extend(steps)
+            final_configs = steps.final_configs()
         count("tune.records_built", N * S)
+        # registered at 0, so a run that reads no record's config reports it
+        count("tune.record_configs_built", 0)
 
         # ---- replay the chosen bins into the adaptive oracle ---------------
         # (paper-§2.4.1 split/extend/merge runs host-side BETWEEN batches;
@@ -1295,7 +1282,7 @@ class DeviceEpisodeRunner:
         # subsequence goes through ONE batched record_many (which falls back
         # to the exact per-assignment loop whenever a rule could fire
         # mid-batch) instead of N·S python record() calls.
-        bins = self.cfgr.disc.bins
+        bins, names = self.cfgr.disc.bins, table.names
         replayed = 0
         with span("tune.replay", batch=batch) as sp:
             lever_sm = lever.T.ravel()        # (S·N,) step-major
