@@ -37,16 +37,39 @@ unless that file names it under ``not_compared`` with the reason):
   checkpoint that does not restore the promoted incumbent), both exact.
 
 A number that is not finite counts as failing.
+
+The reference is ``reference.py``, or a configuration's own
+(``reference_for``); every function below that runs the reference takes it
+as ``ref``.
 """
 from __future__ import annotations
 
 import math
+import types
+from pathlib import Path
 
 import numpy as np
 
-from reference import (Precision, action_logp, bin_of, decode_lever,
-                       encode_states, lever_frac, policy_update, replay_steps,
-                       step_bin)
+import lookup
+import reference
+from reference import Precision
+
+
+def reference_for(deploy: dict, bench: Path):
+    """The reference of a configuration's cells: ``reference`` itself, or,
+    where ``references/<config>.py`` exists under the cell's benchmark
+    directory, that file laid over a fresh copy of ``reference.py`` — so a
+    function it redefines (``service_seconds``, say) is the one every
+    function of the copy calls, and it may import ``reference`` for the
+    originals."""
+    name = deploy["name"]
+    path = lookup.path_of(bench, "references", name)
+    if not path.is_file():
+        return reference
+    mod = types.ModuleType(f"reference_{name}")
+    for src in (Path(reference.__file__), path):
+        exec(compile(src.read_text(), str(src), "exec"), mod.__dict__)
+    return mod
 
 
 def _finite_or_inf(x: float) -> float:
@@ -83,7 +106,7 @@ def apply_errors(records: list, configs0: list, ranked: list, *, n: int,
 
 
 def replay(deploy: dict, traffic: dict, records: list, configs0: list, *,
-           n: int, s: int, rng, q: Precision) -> dict:
+           n: int, s: int, rng, q: Precision, ref, bench: Path) -> dict:
     """The reference's (N, steps) arrays for the first update's (or
     cycle's) steps, replayed from the configs and loading times the program
     applied, starting from the configs deployed before them. Only the first
@@ -100,18 +123,19 @@ def replay(deploy: dict, traffic: dict, records: list, configs0: list, *,
     # step: a whole number of that config's ticks
     T_b0 = np.array([c["batch_interval_s"] for c in configs0], float)
     clock0 = np.maximum(np.round(float(deploy["window_s"]) / T_b0), 1.0) * T_b0
-    return replay_steps(deploy, inputs,
-                        lanes=int(traffic["p99_lanes_per_tick"]), rng=rng,
-                        q=q, clock0=clock0, reset_each=resets)
+    return ref.replay_steps(deploy, inputs,
+                            lanes=int(traffic["p99_lanes_per_tick"]), rng=rng,
+                            q=q, clock0=clock0, reset_each=resets, bench=bench)
 
 
 def simulation_numbers(deploy: dict, traffic: dict, records: list,
-                       configs0: list, *, n: int, s: int, rng) -> dict:
+                       configs0: list, *, n: int, s: int, rng, ref,
+                       bench: Path) -> dict:
     """stab/clock/p99/reward numbers over the first update's (or cycle's)
     steps, against the float64 reference."""
     steps = step_records(records, n, s, 1)
-    ref = replay(deploy, traffic, records, configs0, n=n, s=s, rng=rng,
-                 q=Precision("float64"))
+    want = replay(deploy, traffic, records, configs0, n=n, s=s, rng=rng,
+                  q=Precision("float64"), ref=ref, bench=bench)
     prog = {
         "stab_s": np.array([[st[i].phases["stabilisation_s"] for st in steps]
                             for i in range(n)]),
@@ -122,7 +146,7 @@ def simulation_numbers(deploy: dict, traffic: dict, records: list,
         "reward": np.array([[st[i].reward for st in steps]
                             for i in range(n)]),
     }
-    return compare_simulation(prog, ref)
+    return compare_simulation(prog, want)
 
 
 def compare_simulation(prog: dict, ref: dict) -> dict:
@@ -146,7 +170,7 @@ def compare_simulation(prog: dict, ref: dict) -> dict:
 
 
 def act_reference(deploy: dict, enc: dict, actions, configs0: list, *,
-                  q: Precision) -> dict:
+                  q: Precision, ref) -> dict:
     """What the first update's (or cycle's) steps should have seen and
     applied, from the encoder's inputs before its first step (``enc``:
     the per-node metrics and the running range) and its actions (N, S):
@@ -155,22 +179,22 @@ def act_reference(deploy: dict, enc: dict, actions, configs0: list, *,
     space, ranked = deploy["lever_space"], deploy["ranked_levers"]
     actions = np.asarray(actions)
     n, s = actions.shape
-    bins = np.array([[bin_of(space[l], c[l], q) for l in ranked]
+    bins = np.array([[ref.bin_of(space[l], c[l], q) for l in ranked]
                      for c in configs0[:n]])
     fracs = np.zeros((n, s, len(ranked)))
     levers = np.empty((n, s), object)
     values = np.zeros((n, s))
     for t in range(s):
-        fracs[:, t] = [[lever_frac(space[l], b[j]) for j, l in enumerate(ranked)]
-                       for b in bins]
+        fracs[:, t] = [[ref.lever_frac(space[l], b[j])
+                        for j, l in enumerate(ranked)] for b in bins]
         for i in range(n):
             j, d = divmod(int(actions[i, t]), 2)
             lever = space[ranked[j]]
-            bins[i, j] = step_bin(lever, int(bins[i, j]), 1 - 2 * d)
+            bins[i, j] = ref.step_bin(lever, int(bins[i, j]), 1 - 2 * d)
             levers[i, t] = ranked[j]
-            values[i, t] = float(decode_lever(lever, int(bins[i, j]), q))
-    states0, _, _ = encode_states(enc["per_node"], enc["lo"], enc["hi"],
-                                  fracs[:, 0], q)
+            values[i, t] = float(ref.decode_lever(lever, int(bins[i, j]), q))
+    states0, _, _ = ref.encode_states(enc["per_node"], enc["lo"], enc["hi"],
+                                      fracs[:, 0], q)
     return {"states0": states0, "fracs": fracs, "levers": levers,
             "directions": 1 - 2 * (actions % 2), "values": values}
 
@@ -206,7 +230,8 @@ def compare_act(prog: dict, ref: dict) -> dict:
             "decode_rel_err": _finite_or_inf(dec)}
 
 
-def act_z(init: dict, batches: list, *, f: float, warmup: int) -> float:
+def act_z(init: dict, batches: list, *, f: float, warmup: int,
+          ref) -> float:
     """|z| of the checked updates' actions under the policy's own draw: the
     reference follows the updates from the same initial weights on the
     program's batches, and at each update scores the actions taken by
@@ -217,18 +242,18 @@ def act_z(init: dict, batches: list, *, f: float, warmup: int) -> float:
     nu = {k: np.zeros_like(v) for k, v in p.items()}
     num = var = 0.0
     for k, (states, actions, rewards) in enumerate(batches):
-        logp = action_logp(p, states, q, exploit=k >= warmup, f=f)
+        logp = ref.action_logp(p, states, q, exploit=k >= warmup, f=f)
         prob = np.exp(logp)
         ent = -(prob * logp).sum(-1)
         chosen = np.take_along_axis(logp, actions[..., None], -1)[..., 0]
         num += float((chosen + ent).sum())
         var += float(((prob * logp ** 2).sum(-1) - ent ** 2).sum())
-        p, nu, *_ = policy_update(p, nu, states, actions, rewards, q=q)
+        p, nu, *_ = ref.policy_update(p, nu, states, actions, rewards, q=q)
     return _finite_or_inf(abs(num) / math.sqrt(max(var, 1e-300)))
 
 
 def update_numbers(init: dict, batches: list, losses: list, nu1: dict,
-                   params_end: dict) -> dict:
+                   params_end: dict, *, ref) -> dict:
     """loss/grad/change numbers: the reference follows the checked updates
     from the same initial weights on the program's own batches."""
     q = Precision("float64")
@@ -236,8 +261,8 @@ def update_numbers(init: dict, batches: list, losses: list, nu1: dict,
     nu = {k: np.zeros_like(v) for k, v in p.items()}
     ref_losses, scales, g1 = [], [], None
     for states, actions, rewards in batches:
-        p, nu, grads, loss, scale = policy_update(p, nu, states, actions,
-                                                  rewards, q=q)
+        p, nu, grads, loss, scale = ref.policy_update(p, nu, states, actions,
+                                                      rewards, q=q)
         g1 = grads if g1 is None else g1
         ref_losses.append(loss)
         scales.append(scale)

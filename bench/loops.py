@@ -13,6 +13,10 @@ ends, as the launchers run them.
 * ``serve_plane`` — ``ServeController.run_cycle``, built as
   ``repro.launch.serve --quick`` builds it, with checkpoints and the
   episode history written to a scratch directory.
+
+Any other kind is ``drivers/<kind>.py``'s ``DRIVER`` (``driver_for``), and
+any workload law not built in here is ``laws/<law>.py``
+(``make_workloads``), both under the cell's benchmark directory.
 """
 from __future__ import annotations
 
@@ -23,6 +27,7 @@ from pathlib import Path
 import numpy as np
 
 import compare
+import lookup
 from reference import Precision, init_policy
 
 
@@ -62,25 +67,34 @@ def program_seed(seed: int) -> int:
     return int(np.random.default_rng([seed, 0]).integers(1, 2**30))
 
 
-def make_workloads(law: dict, n: int) -> list:
+def make_workloads(law: dict, n: int, seed: int, bench: Path) -> list:
+    """The ``n`` clusters' workloads of ``law``. A law that is not built in
+    is the ``program`` half of ``laws/<law>.py`` under the cell's benchmark
+    directory ``bench``, given the program's seed (the built-in laws have no
+    use for it)."""
     from repro.data.workloads import (PoissonWorkload, SwitchingWorkload,
                                       YahooAdsWorkload)
 
-    def one(l):
-        if l["law"] == "poisson":
-            return PoissonWorkload(float(l["rate"]), float(l["event_size_mb"]))
-        if l["law"] == "diurnal":
-            return YahooAdsWorkload(base_rate=float(l["rate"]),
-                                    diurnal_amp=float(l["amplitude"]),
-                                    day_s=float(l["day_s"]),
-                                    event_size_mb=float(l["event_size_mb"]),
-                                    n_campaigns=int(l["campaigns"]))
-        if l["law"] == "switching":
-            return SwitchingWorkload(one(l["a"]), one(l["b"]),
-                                     period_s=float(l["period_s"]))
-        raise ValueError(f"unknown workload law {l['law']!r}")
-
-    return [one(law) for _ in range(n)]
+    if law["law"] == "poisson":
+        return [PoissonWorkload(float(law["rate"]),
+                                float(law["event_size_mb"]))
+                for _ in range(n)]
+    if law["law"] == "diurnal":
+        return [YahooAdsWorkload(base_rate=float(law["rate"]),
+                                 diurnal_amp=float(law["amplitude"]),
+                                 day_s=float(law["day_s"]),
+                                 event_size_mb=float(law["event_size_mb"]),
+                                 n_campaigns=int(law["campaigns"]))
+                for _ in range(n)]
+    if law["law"] == "switching":
+        return [SwitchingWorkload(a, b, period_s=float(law["period_s"]))
+                for a, b in zip(make_workloads(law["a"], n, seed, bench),
+                                make_workloads(law["b"], n, seed, bench))]
+    out = list(lookup.load(bench, "laws", law["law"]).program(law, n, seed))
+    if len(out) != n:
+        raise ValueError(f"bench: law {law['law']!r} built {len(out)} "
+                         f"workloads for {n} clusters")
+    return out
 
 
 def sim_spec(deploy: dict):
@@ -172,12 +186,14 @@ def checked_numbers(driver) -> dict:
     """The numbers ``compare.py`` reads for a driver's checked updates (or
     cycles): the replay of the first of them, the encode
     and decode of the first, the apply check and the actions over all, and
-    the policy updates."""
+    the policy updates, against the configuration's reference."""
     t = driver.traffic
+    ref = compare.reference_for(driver.deploy, driver.bench)
     out = compare.simulation_numbers(
         driver.deploy, t, driver.checked_records, driver.configs0,
         n=driver.n, s=driver.s,
-        rng=np.random.default_rng([driver.seed, 2]))
+        rng=np.random.default_rng([driver.seed, 2]), ref=ref,
+        bench=driver.bench)
     out["apply_errors"] = compare.apply_errors(
         driver.checked_records, driver.configs0, driver.deploy["ranked_levers"],
         n=driver.n, s=driver.s, units=int(t["checked_units"]))
@@ -189,27 +205,29 @@ def checked_numbers(driver) -> dict:
                             s=driver.s, ranked=ranked),
         compare.act_reference(driver.deploy, sp.enc, actions,
                               driver.configs0,
-                              q=Precision("float64"))))
+                              q=Precision("float64"), ref=ref)))
     out["act_z"] = compare.act_z(driver.init, sp.batches,
                                  f=float(t["f_exploit"]),
-                                 warmup=int(t["f_warmup_updates"]))
+                                 warmup=int(t["f_warmup_updates"]), ref=ref)
     out.update(compare.update_numbers(driver.init, sp.batches, sp.losses,
-                                      sp.nu1, sp.params_end))
+                                      sp.nu1, sp.params_end, ref=ref))
     return out
 
 
 class TrainLoop:
     unit = "update"
 
-    def __init__(self, deploy: dict, traffic: dict, seed: int, workdir: Path):
+    def __init__(self, deploy: dict, traffic: dict, seed: int, workdir: Path,
+                 bench: Path):
         from repro.core.configurator import Configurator
         from repro.engine import FleetEnv
 
         self.deploy, self.traffic, self.seed = deploy, traffic, seed
+        self.bench = bench
         ps = program_seed(seed)
         self.n = int(traffic["fleet"])
         self.s = int(traffic["steps_per_episode"])
-        env = FleetEnv(make_workloads(deploy["workload"], self.n),
+        env = FleetEnv(make_workloads(deploy["workload"], self.n, ps, bench),
                        model=served_model(deploy), spec=sim_spec(deploy),
                        seed=ps, backend=traffic["backend"])
         self.env = env
@@ -276,8 +294,10 @@ class TrainLoop:
 class ServePlane:
     unit = "cycle"
 
-    def __init__(self, deploy: dict, traffic: dict, seed: int, workdir: Path):
+    def __init__(self, deploy: dict, traffic: dict, seed: int, workdir: Path,
+                 bench: Path):
         self.deploy, self.traffic, self.seed = deploy, traffic, seed
+        self.bench = bench
         self.n = int(traffic["fleet"])
         self.s = int(traffic["steps_per_episode"])
         self.workdir = Path(workdir)
@@ -309,7 +329,8 @@ class ServePlane:
 
         env_kw = dict(model=served_model(self.deploy),
                       spec=sim_spec(self.deploy))
-        ctl = ServeController(make_workloads(self.deploy["workload"], self.n),
+        ctl = ServeController(make_workloads(self.deploy["workload"], self.n,
+                                             self.kw["seed"], self.bench),
                               checkpoint_dir=workdir / "ck",
                               history_path=workdir / "history.jsonl",
                               **self.kw)
@@ -417,3 +438,12 @@ class ServePlane:
 
 
 DRIVERS = {"train_loop": TrainLoop, "serve_plane": ServePlane}
+
+
+def driver_for(kind: str, bench: Path):
+    """The driver class of a traffic ``kind``: a built-in one, else
+    ``drivers/<kind>.py``'s ``DRIVER`` under the cell's benchmark directory
+    ``bench``. Each is built as ``(deploy, traffic, seed, workdir, bench)``."""
+    if kind in DRIVERS:
+        return DRIVERS[kind]
+    return lookup.load(bench, "drivers", kind).DRIVER

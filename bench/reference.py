@@ -4,7 +4,10 @@ Written from the model's stated equations in straightforward numpy, one
 statement at a time, and independent of the program under test: it imports
 nothing from ``src/`` and reads nothing the program made except the answers
 it checks (the applied configs, loading times, window statistics, rewards and
-the policy-update batches the timed path produced).
+the policy-update batches the timed path produced). A workload law it does
+not build in is the ``reference`` half of the cell's ``laws/<law>.py``, and a
+configuration may bring its own copy of this module
+(``compare.reference_for``).
 
 Three parts:
 
@@ -34,6 +37,8 @@ for a lower-precision program).
 from __future__ import annotations
 
 import numpy as np
+
+import lookup
 
 R2PI = float(np.sqrt(2.0 / np.pi))       # E|N(0,1)|
 
@@ -129,8 +134,10 @@ def service_seconds(cc: dict, batch, size, deploy: dict, q: Precision):
     return q(q(ovh + q(t_comp * pen)) + coll)
 
 
-def arrival_law(law: dict, t, q: Precision):
-    """(events/s, MB/event) of a workload law at times ``t``."""
+def arrival_law(law: dict, t, q: Precision, bench):
+    """(events/s, MB/event) of a workload law at times ``t``. A law that is
+    not built in is the ``reference`` half of ``laws/<law>.py`` under the
+    cell's benchmark directory ``bench``."""
     kind = law["law"]
     if kind == "poisson":
         return (np.full(np.shape(t), float(law["rate"])),
@@ -141,10 +148,10 @@ def arrival_law(law: dict, t, q: Precision):
         return rate, np.full(np.shape(t), float(law["event_size_mb"]))
     if kind == "switching":
         use_a = np.mod(np.floor(q(t) / law["period_s"]), 2.0) < 0.5
-        ra, sa = arrival_law(law["a"], t, q)
-        rb, sb = arrival_law(law["b"], t, q)
+        ra, sa = arrival_law(law["a"], t, q, bench)
+        rb, sb = arrival_law(law["b"], t, q, bench)
         return np.where(use_a, ra, rb), np.where(use_a, sa, sb)
-    raise ValueError(f"unknown workload law {kind!r}")
+    return lookup.load(bench, "laws", kind).reference(law, t, q)
 
 
 # --------------------------------------------------------------------------
@@ -247,13 +254,15 @@ def action_logp(params: dict, states, q: Precision, *, exploit: bool,
 
 def replay_steps(deploy: dict, steps: list, *, lanes: int, rng,
                  q: Precision, clock0, last_service=None,
-                 reset_each: set = frozenset()):
+                 reset_each: set = frozenset(), bench):
     """Replay K clusters through ``len(steps)`` shadow steps.
 
     ``steps[k]`` holds the step's applied configs (``configs``, K dicts)
     and loading seconds (``load_s``, (K,)). ``clock0`` is the clock before
     the first step; ``reset_each`` names the steps before which the queues
-    start empty (a serve cycle spins its shadow replicas up fresh).
+    start empty (a serve cycle spins its shadow replicas up fresh);
+    ``bench`` is the cell's benchmark directory, where a workload law that
+    is not built in is found.
     The window mean is analytic: each window tick's latency mixture mean,
     weighted by its sampled events.
     Returns per-step (K,) arrays: ``stab_s``, ``clock_s``, ``mean_ms``,
@@ -281,12 +290,12 @@ def replay_steps(deploy: dict, steps: list, *, lanes: int, rng,
         load = np.asarray(st["load_s"], np.float64)
         T_b = cc["T_b"]
         # ---- loading: Kafka buffers arrivals while the config deploys ----
-        rate_now, _ = arrival_law(law, clock, q)
+        rate_now, _ = arrival_law(law, clock, q, bench)
         backlog = q(backlog + q(rate_now * load))
         clock = q(clock + load)
         sfree = q(np.maximum(sfree - load, 0.0))
         # ---- stabilisation wait from the service-term change ----
-        rate_st, size_st = arrival_law(law, clock, q)
+        rate_st, size_st = arrival_law(law, clock, q, bench)
         s_new = service_seconds(cc, q(np.minimum(q(rate_st * T_b),
                                                  cc["max_b"])),
                                 size_st, deploy, q)
@@ -315,7 +324,7 @@ def replay_steps(deploy: dict, steps: list, *, lanes: int, rng,
             active = t < n_ticks
             in_win = active & (t >= n_skip)
             times = q(clock + q(t * T_b))
-            rate, size = arrival_law(law, times, q)
+            rate, size = arrival_law(law, times, q, bench)
             z = rng.standard_normal(K)
             arr = q(np.maximum(q(q(rate * T_b) * q(1.0 + cl["noise"] * z)), 0.0))
             age = q(backlog / np.maximum(rate, 1.0))

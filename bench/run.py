@@ -7,7 +7,10 @@ A run is one process and holds the chip for its whole life:
 
 1. looks up the cell in ``BENCHMARK.json`` and its files by name
    (``configs/<config>.json``, ``traffic/<traffic>.json``,
-   ``limits/<cell>.json``, ``metrics/<metric>.py``);
+   ``limits/<cell>.json``, ``metrics/<metric>.py``, and where the cell's
+   workload law, reference or traffic kind is not built in,
+   ``laws/<law>.py``, ``references/<config>.py``, ``drivers/<kind>.py``:
+   ``lookup.py``);
 2. exits non-zero, printing no result, unless JAX sees a TPU with as many
    chips as the cell asks for;
 3. builds the system from the seed, drives its first updates (or serve
@@ -26,7 +29,6 @@ and keeps every program, so only a cell's first run in a checkout compiles.
 from __future__ import annotations
 
 import argparse
-import importlib.util
 import json
 import math
 import os
@@ -40,6 +42,8 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 CACHE_DIR = ROOT / ".jax_cache"
 sys.path.insert(0, str(BENCH))
+
+import lookup  # noqa: E402
 
 
 # --------------------------------------------------------------------------
@@ -77,11 +81,7 @@ def load_cell(name: str, root: Path = ROOT) -> SimpleNamespace:
 
 def metric_reader(bench: Path, name: str):
     """``metrics/<name>.py``'s ``read(ctx)``."""
-    path = bench / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return lookup.load(bench, "metrics", name).read
 
 
 # --------------------------------------------------------------------------
@@ -203,8 +203,8 @@ def measure(cell, args, devs, t_start: float) -> dict:
 
     traffic = cell.traffic
     with tempfile.TemporaryDirectory(prefix="bench-") as work:
-        driver = loops.DRIVERS[traffic["kind"]](cell.deploy, traffic,
-                                                int(args.seed), Path(work))
+        driver = loops.driver_for(traffic["kind"], cell.bench)(
+            cell.deploy, traffic, int(args.seed), Path(work), cell.bench)
         driver.setup()
         c0 = clock.snapshot()
         setup_s = time.perf_counter() - t_start

@@ -25,7 +25,8 @@ SMALL = {"fleet": 8, "warmup_units": 0}
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_resolves_to_its_files(cell):
     c = run.load_cell(cell)
-    assert c.traffic["kind"] in loops.DRIVERS
+    assert loops.driver_for(c.traffic["kind"], c.bench) \
+        is loops.DRIVERS[c.traffic["kind"]]
     assert (BENCH / "limits" / f"{cell}.json").exists()
     assert c.deploy["name"] == c.cell["config"]
     assert {m["name"] for m in c.e2e} >= {"setup_s"}

@@ -108,14 +108,15 @@ def wrong_bin(driver):
     runner._materialise = mat
 
 
-def run_small(cell, seed, monkeypatch, fault=None):
-    """A whole harness run at N=8 on the CPU, with ``fault`` planted in
-    each driver the harness builds. The warm-up after the checked updates
-    only compiles shapes (it is judged by the whole run of
-    ``test_bench_cells.py``), so it is skipped here."""
+def run_small(cell, seed, monkeypatch, fault=None, root=ROOT):
+    """A whole harness run at N=8 on the CPU of a cell of ``root``'s
+    ``BENCHMARK.json``, with ``fault`` planted in each driver the harness
+    builds. The warm-up after the checked updates only compiles shapes (it
+    is judged by the whole run of ``test_bench_cells.py``), so it is skipped
+    here."""
     import jax
 
-    c = run.load_cell(cell)
+    c = run.load_cell(cell, root)
     c.traffic.update(SMALL)
     monkeypatch.setattr(loops, "warm_up", lambda cfgr, traffic, one_unit: 0)
     if fault is not None:
@@ -143,8 +144,9 @@ def test_control_fails_a_number(cell):
     c = run.load_cell(cell)
     sys.path.insert(0, str(ROOT / "src"))
     with tempfile.TemporaryDirectory() as work:
-        d = loops.DRIVERS[c.traffic["kind"]](
-            c.deploy, dict(c.traffic, **SMALL), 2**31 + 41, Path(work))
+        d = loops.driver_for(c.traffic["kind"], c.bench)(
+            c.deploy, dict(c.traffic, **SMALL), 2**31 + 41, Path(work),
+            c.bench)
         d.check_units()
         r = calibrate.readings(d)
     _, prog = compare.judge(r["program"], c.limits, c.not_compared)
